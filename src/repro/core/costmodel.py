@@ -13,12 +13,18 @@ Two device profiles are built in:
     used to reproduce the paper's own numbers;
   * TPUV5E — the deployment target (197 TFLOPS bf16, 819 GB/s HBM,
     ~50 GB/s/link ICI), used for the TPU-native roofline in EXPERIMENTS.md.
+
+``attached_device()`` picks the profile of the attached TPU from its
+``device_kind`` through ``DEVICE_PROFILES``; the serving engine and the JIT
+default to it.
 """
 from __future__ import annotations
 
 import dataclasses
 import math
 from typing import Optional, Sequence, Tuple
+
+import jax
 
 
 @dataclasses.dataclass(frozen=True)
@@ -76,6 +82,29 @@ TPUV5E = Device(
     ici_bw=50e9,
     vector_flops=4e12,         # VPU
 )
+
+
+# Cost-model profile of each supported accelerator, keyed by the
+# ``device_kind`` JAX reports (a v5e chip reports "TPU v5 lite").
+DEVICE_PROFILES = {"TPU v5 lite": TPUV5E, "TPU v5e": TPUV5E}
+
+
+def attached_device() -> Device:
+    """The profile of the attached accelerator.
+
+    On a TPU backend it is ``DEVICE_PROFILES[device_kind]``, and a TPU kind
+    missing from the table is an error rather than a silent v5e. Without a
+    TPU (the CPU test backend) it is ``TPUV5E``, the deployment target the
+    modeled clock stands in for."""
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return TPUV5E
+    try:
+        return DEVICE_PROFILES[dev.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no cost-model profile for TPU kind {dev.device_kind!r}; add "
+            f"one to costmodel.DEVICE_PROFILES") from None
 
 
 @dataclasses.dataclass(frozen=True)

@@ -55,9 +55,10 @@ Memory note: cached packed weights are full padded copies — on a real
 deployment this is the point (the packed operand lives in HBM across ticks
 instead of being re-staged), but the footprint must be bounded in BYTES,
 not entries (one entry can be hundreds of MB at real model sizes):
-``VLIWJit(weight_budget_bytes=...)`` sets the LRU byte budget (default
-1 GiB), ``weight_capacity`` the entry count, and ``capacity=0`` disables
-the cache entirely (the repack-per-tick baseline, still jitted).
+``VLIWJit(weight_budget_bytes=...)`` sets the LRU byte budget (default:
+``device_weight_budget``, half the memory of the devices served),
+``weight_capacity`` the entry count, and ``capacity=0`` disables the cache
+entirely (the repack-per-tick baseline, still jitted).
 """
 from __future__ import annotations
 
@@ -73,11 +74,11 @@ from repro.core.costmodel import BlockConfig
 from repro.core.kernelspec import KernelOp
 from repro.core.schedtrace import OperandIdentityHazard
 from repro.core.plancache import PlanCache
+from repro.kernels.backend import interpret_default
 from repro.kernels.coalesced_gemm import coalesced_gemm
 from repro.kernels.coalesced_gemv import coalesced_gemv
 from repro.kernels.ops import (_round_up, check_vmem, coalesced_matvec,
-                               envelope_bucket, execute_superkernel,
-                               interpret_default)
+                               envelope_bucket, execute_superkernel)
 
 
 # ---------------------------------------------------------------------------
@@ -198,6 +199,23 @@ def _dispatch_matvec(xs, w_stacked, *, n_real, bn, bk, interpret):
     return tuple(out[i, :n] for i, n in enumerate(n_real))
 
 
+def device_weight_budget(n_devices: int = 1) -> int:
+    """Default byte budget of the packed-weight cache serving the first
+    ``n_devices`` JAX devices: half of each device's memory
+    (``memory_stats()["bytes_limit"]``), leaving the other half to the
+    params, KV caches and activations. Packed operands are padded copies of
+    the weights, so at published widths the cache must be sized like the
+    model itself — a fixed cap smaller than one tenant's operands would
+    bypass or evict them and repack gigabytes every tick. A device that
+    reports no memory (the CPU backend) counts 1 GiB."""
+    total = 0
+    for dev in jax.devices()[:max(1, n_devices)]:
+        stats = dev.memory_stats()
+        total += stats["bytes_limit"] // 2 if stats else 1 << 30
+    # a modeled mesh wider than the attached devices counts 1 GiB a slot
+    return total + (1 << 30) * max(0, n_devices - len(jax.devices()))
+
+
 def _pow2(n: int) -> int:
     """Smallest power of two ≥ n (n ≥ 1)."""
     return 1 << max(n - 1, 0).bit_length()
@@ -232,12 +250,10 @@ class SuperkernelExecutor:
         # are full padded copies, so an entry-count bound alone does not
         # bound memory (see the module docstring's memory note)
         self.weight_cache = weight_cache if weight_cache is not None \
-            else PlanCache(256, byte_capacity=1 << 30)
+            else PlanCache(256, byte_capacity=device_weight_budget())
         self.bm, self.bn, self.bk = bm, bn, bk
         self.enabled = enabled
-        # resolved at construction from the CURRENT process default (not
-        # the import-time value): a bench that probes the compiled lane
-        # and falls back via ops.set_interpret gets interpret executors
+        # compiled on a TPU, interpreted only on a CPU backend
         self.interpret = interpret_default() if interpret is None \
             else interpret
         self.stats = DispatchStats()

@@ -119,9 +119,11 @@ from repro.core.clustering import (is_expert_op, op_weight_identity,
                                    op_weight_key, shared_weight_key,
                                    weight_key)
 from repro.core.coalescer import Coalescer
-from repro.core.costmodel import BlockConfig, CostModel, GemmShape, TPUV5E
+from repro.core.costmodel import (BlockConfig, CostModel, GemmShape,
+                                  attached_device)
 from repro.core.dispatch import (DispatchStats, SuperkernelExecutor,
-                                 _tile_bucket, envelope_bucket)
+                                 _tile_bucket, device_weight_budget,
+                                 envelope_bucket)
 from repro.core.kernelspec import make_op, op_aspect
 from repro.core.plancache import PlanCache, PlanCacheStats
 from repro.core.scheduler import OoOScheduler, SchedulerConfig
@@ -2122,10 +2124,10 @@ class VLIWJit:
                  max_group: int = 16, bm: int = 8,
                  plan_capacity: int = 128,
                  weight_capacity: Optional[int] = None,
-                 weight_budget_bytes: Optional[int] = 1 << 30,
+                 weight_budget_bytes: Optional[int] = None,
                  live_tune: bool = False,
                  tune_objective: str = "collaborative"):
-        self.cost = cost or CostModel(TPUV5E)
+        self.cost = cost or CostModel(attached_device())
         # persistent plan caches (core/plancache.py): program templates for
         # the serving hot path and superkernel block plans per coalesced
         # group signature. They live on the JIT — across sessions — so
@@ -2158,11 +2160,13 @@ class VLIWJit:
         # the entry-count bound (weight_capacity, default tracks
         # plan_capacity; 0 = repack per dispatch, still jitted) does NOT
         # bound memory at real model sizes — weight_budget_bytes does (LRU
-        # evicts past the byte budget, default 1 GiB; None = unbounded).
+        # evicts past the byte budget; default None = half the memory of
+        # the attached device, dispatch.device_weight_budget).
         wcap = 2 * plan_capacity if weight_capacity is None else \
             weight_capacity
-        self.weight_cache = PlanCache(wcap,
-                                      byte_capacity=weight_budget_bytes)
+        self.weight_cache = PlanCache(
+            wcap, byte_capacity=device_weight_budget()
+            if weight_budget_bytes is None else weight_budget_bytes)
         self.executor = SuperkernelExecutor(self.weight_cache, bm=bm)
 
     def session(self, record_trace: bool = False, *, device: int = 0,

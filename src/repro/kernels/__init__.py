@@ -1,7 +1,9 @@
 """Pallas TPU kernels for the perf-critical compute the paper optimizes:
 the coalesced (grouped) GEMM superkernel, the coalesced GEMV, and windowed
 flash attention. Each has a pure-jnp oracle in ref.py; ops.py holds the
-jit'd packing wrappers. Kernels are validated in interpret mode on CPU.
+jit'd packing wrappers. Kernels are compiled by Mosaic on a TPU and run in
+interpret mode only on a CPU backend (backend.py), where the tests validate
+them; tests/test_tpu_compile.py compiles them for a described TPU.
 """
 from repro.kernels.coalesced_gemm import coalesced_gemm
 from repro.kernels.coalesced_gemv import coalesced_gemv

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import tpu_compiler_params
+from repro.kernels.backend import interpret_default
 
 
 def _kernel(gid_ref, a_ref, b_ref, o_ref, acc_ref, *, nk: int):
@@ -42,7 +42,7 @@ def _kernel(gid_ref, a_ref, b_ref, o_ref, acc_ref, *, nk: int):
                    static_argnames=("bm", "bn", "bk", "interpret"))
 def coalesced_gemm(a_packed: jax.Array, b_stacked: jax.Array,
                    group_ids: jax.Array, *, bm: int = 128, bn: int = 128,
-                   bk: int = 512, interpret: bool = True) -> jax.Array:
+                   bk: int = 512, interpret: bool | None = None) -> jax.Array:
     """Run the grouped superkernel.
 
     a_packed:  [M_pad, K]    problems concatenated along m (rows padded per
@@ -75,7 +75,7 @@ def coalesced_gemm(a_packed: jax.Array, b_stacked: jax.Array,
         functools.partial(_kernel, nk=nk),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, N), a_packed.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=interpret_default() if interpret is None else interpret,
     )(group_ids, a_packed, b_stacked)

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import tpu_compiler_params
+from repro.kernels.backend import interpret_default
 
 _NEG = -2.0e38
 
@@ -68,7 +68,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
                                              "interpret"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     bq: int = 128, bkv: int = 128, causal: bool = True,
-                    window: int = 0, interpret: bool = True) -> jax.Array:
+                    window: int = 0, interpret: bool | None = None) -> jax.Array:
     """q, k, v: [BH, S, D] -> [BH, S, D]."""
     BH, S, D = q.shape
     bq = min(bq, S)
@@ -93,7 +93,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, D), jnp.float32),
         ],
         out_shape=jax.ShapeDtypeStruct((BH, S, D), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=interpret_default() if interpret is None else interpret,
     )(q, k, v)

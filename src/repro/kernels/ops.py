@@ -13,30 +13,21 @@ fast paths are tested against.
 
 Interpret mode and the compiled lane
 ------------------------------------
-``REPRO_PALLAS_INTERPRET`` selects how every Pallas kernel in this package
-executes (read at import into the module global ``INTERPRET``; callers that
-need the current value at call time use ``interpret_default()`` and tests/
-benches may flip it with ``set_interpret``):
+Every Pallas call in this package takes ``interpret=None`` by default and
+resolves it from the attached backend when it is called
+(``kernels.backend.interpret_default``): interpret mode on a CPU backend,
+where Pallas cannot compile a TPU kernel (the test suite runs there), and
+Mosaic-compiled kernels everywhere else. There is no switch: on a TPU every
+kernel on the serving path is compiled, and a kernel the compiler refuses
+fails the run. A caller may still pass ``interpret`` explicitly — the
+compile-only tests (tests/test_tpu_compile.py) pass ``interpret=False`` to
+compile for a described TPU from a CPU host.
 
-  * unset / ``1`` (default) — ``pl.pallas_call(interpret=True)``: the kernel
-    body runs as traced JAX ops on the host platform (CPU in this
-    container). Correctness-exact, required wherever no TPU is attached.
-  * ``0`` — the COMPILED lane: Mosaic-compiled kernels on a real TPU
-    deployment. ``compiled_lane_available()`` probes whether the attached
-    backend can actually compile a Pallas kernel (a CPU-only host cannot —
-    jax raises "Only interpret mode is supported on CPU backend"); callers
-    that were asked for the compiled lane but find it unavailable should
-    fall back to interpret mode and SKIP wall-clock claims, not fail.
-
-Compiled-lane policy: interpret mode pays a ~2 ms/grid-step host floor, so
-interpret-mode WALL-CLOCK numbers only measure dispatch-layer overheads
-(packing, retraces, cache traffic) — kernel-level effects (tile geometry,
-VMEM residency) are invisible under the floor. Wall-clock comparisons of
-*block configs* (the autotuner's subject) are therefore only meaningful on
-the compiled lane at realistic dims (k, n ≥ 1024); everywhere else the
-analytic cost model is the arbiter and interpret-mode runs gate
-correctness (bit-identity, cache hit rates, retrace counts) only.
-``benchmarks/compiled_autotune_bench.py`` implements exactly this split.
+Interpret mode pays a ~2 ms/grid-step host floor, so interpret-mode
+wall-clock numbers measure only dispatch-layer overheads (packing,
+retraces, cache traffic); there it gates correctness (bit-identity, cache
+hit rates, retrace counts) and nothing else. Tile geometry and VMEM
+residency show only in compiled runs on the chip.
 
 Compiled tiles must also fit VMEM: ``check_vmem`` raises a clear error
 before dispatching a compiled kernel whose per-tile working set
@@ -76,64 +67,21 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import os
 from typing import List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.backend import interpret_default
 from repro.kernels.coalesced_gemm import coalesced_gemm
 from repro.kernels.coalesced_gemv import coalesced_gemv
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels import ref
 
-# See "Interpret mode and the compiled lane" in the module docstring.
-import os
-INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
-
 # VMEM budget the compiled-lane guard checks tiles against (TPU v5e:
 # ~16 MiB/core). Overridable for smaller parts / headroom experiments.
 VMEM_BYTES = int(os.environ.get("REPRO_VMEM_BYTES", 16 * 1024 * 1024))
-
-
-def interpret_default() -> bool:
-    """The CURRENT interpret-mode default. Prefer this over importing the
-    ``INTERPRET`` name: an import binds the value once, silently ignoring a
-    later ``set_interpret`` (the compiled-lane bench falls back to
-    interpret mode at runtime when the probe fails)."""
-    return INTERPRET
-
-
-def set_interpret(value: bool) -> None:
-    """Flip the process-wide interpret default (see ``interpret_default``).
-    Layers that captured the old value in jit static args keep their
-    compiled executables — flipping only affects dispatches that have not
-    resolved their ``interpret=None`` yet."""
-    global INTERPRET
-    INTERPRET = bool(value)
-
-
-def compiled_lane_available() -> bool:
-    """Whether the attached jax backend can COMPILE a Pallas kernel.
-
-    Probes once per process with a tiny ``coalesced_gemm`` at
-    ``interpret=False``; CPU-only hosts (this container) raise, TPU hosts
-    compile. Benches and parity tests use this to decide between running
-    compiled-lane wall-clock claims and skipping them."""
-    global _COMPILED_LANE
-    if _COMPILED_LANE is None:
-        try:
-            a = jnp.zeros((8, 128), jnp.float32)
-            b = jnp.zeros((1, 128, 128), jnp.float32)
-            gid = jnp.zeros((1,), jnp.int32)
-            jax.block_until_ready(coalesced_gemm(
-                a, b, gid, bm=8, bn=128, bk=128, interpret=False))
-            _COMPILED_LANE = True
-        except Exception:           # noqa: BLE001 — any backend refusal
-            _COMPILED_LANE = False
-    return _COMPILED_LANE
-
-
-_COMPILED_LANE: bool | None = None
 
 
 def vmem_tile_bytes(bm: int, bn: int, bk: int, dtype_bytes: int = 4) -> int:
@@ -220,7 +168,7 @@ def execute_superkernel(problems: Sequence[Tuple[jax.Array, jax.Array]], *,
     decode lockstep case) concatenates activations into a single GEMM so the
     weights stream through VMEM once.
     """
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = interpret_default() if interpret is None else interpret
     if shared_operand:
         b = problems[0][1]
         ms = [int(a.shape[0]) for a, _ in problems]
@@ -257,7 +205,6 @@ def coalesced_matvec(xs: Sequence[jax.Array], ws: Sequence[jax.Array], *,
                      interpret: bool | None = None) -> List[jax.Array]:
     """G matvecs (x [k], w [k, n]). Dispatches the shared-weight GEMM path
     when every problem uses the same weight array."""
-    interpret = INTERPRET if interpret is None else interpret
     shared = all(w is ws[0] for w in ws)
     if shared:
         outs = execute_superkernel(
@@ -277,7 +224,6 @@ def windowed_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                        causal: bool = True, window: int = 0,
                        interpret: bool | None = None) -> jax.Array:
     """[B, H, S, D] flash attention via the Pallas kernel (flattens B×H)."""
-    interpret = INTERPRET if interpret is None else interpret
     B, H, S, D = q.shape
     out = flash_attention(q.reshape(B * H, S, D), k.reshape(B * H, S, D),
                           v.reshape(B * H, S, D), causal=causal,

@@ -1,8 +1,18 @@
 """Serving launcher: bring up the multi-tenant OoO VLIW JIT engine.
 
-Smoke mode runs reduced models on CPU with real token generation; the
-``--mode`` flag selects the multiplexing regime so the paper's comparison
-can be reproduced from the command line.
+By default every tenant is the reduced float32 ``smoke_config`` of its
+architecture, sized for a CPU; ``--layers N`` serves the registry config
+at its published widths in bf16 with only the depth cut to N layers (the
+size for one TPU chip, e.g. ``--tenants yi-9b yi-9b --layers 8``: two
+tenants sharing one params tree). Tenants of the same architecture share
+one (model, params) pair. The ``--mode`` flag selects the multiplexing
+regime so the paper's comparison can be reproduced from the command line.
+``chip_smoke.py`` at the repository root drives this module's builders on
+the chip.
+
+JAX's persistent compilation cache is on (``enable_compile_cache``): in
+``JAX_COMPILATION_CACHE_DIR`` when that is set, else in ``.jax_cache/`` at
+the root of the checkout.
 
 Usage (trace replay — finite trace, virtual time):
   PYTHONPATH=src python -m repro.launch.serve \
@@ -35,31 +45,79 @@ replays the door deterministically on modeled time) to study attainment.
 from __future__ import annotations
 
 import argparse
+import dataclasses
+import os
 import threading
+from pathlib import Path
+from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import ARCH_IDS, smoke_config
+from repro.configs import ARCH_IDS, ModelConfig, get_config, smoke_config
 from repro.models import Model
 from repro.serving import (FrontDoor, ServeRequest, ServingEngine, Tenant,
                            make_trace)
 
+# the checkout's own compile-cache directory (listed in .gitignore)
+CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 
-def _build_models(arch_names):
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX has already read it and
+    nothing is set here. Otherwise the cache lives in ``CACHE_DIR``, one
+    fixed directory inside the checkout — the path is part of the cache
+    key, so it never depends on a temp dir, pid or time."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))
+    return str(CACHE_DIR)
+
+
+def serving_config(arch: str, layers: Optional[int] = None) -> ModelConfig:
+    """The config ``arch`` is served at: with ``layers`` None the reduced
+    ``smoke_config`` (CPU size); otherwise the registry config at its
+    published widths with only the depth cut to ``layers``, which must keep
+    whole periods of the layer pattern."""
+    if layers is None:
+        return smoke_config(arch)
+    full = get_config(arch)
+    period = full.global_every if full.window_size else 1
+    if not 1 <= layers <= full.num_layers or layers % period:
+        raise ValueError(f"{arch}: cannot cut {full.num_layers} layers to "
+                         f"{layers} (whole periods of {period} layers)")
+    if layers == full.num_layers:
+        return full
+    return dataclasses.replace(full, name=f"{full.name}-{layers}l",
+                               num_layers=layers)
+
+
+def build_models(arch_names: Sequence[str], layers: Optional[int] = None,
+                 seed: int = 1) -> Dict[str, Tuple[Model, dict]]:
+    """One (model, params) pair per distinct architecture, weights drawn
+    from ``PRNGKey(seed + i)`` for the i-th. Smoke configs hold float32
+    weights; published widths (``layers`` given) hold bf16, initialized by
+    one jitted call on the default device."""
+    dtype = jnp.float32 if layers is None else jnp.bfloat16
     models = {}
     for i, arch in enumerate(dict.fromkeys(arch_names)):
-        cfg = smoke_config(arch)
-        m = Model(cfg, param_dtype=jnp.float32)
-        models[arch] = (m, m.init(jax.random.PRNGKey(i + 1)))
+        m = Model(serving_config(arch, layers), param_dtype=dtype)
+        models[arch] = (m, jax.jit(m.init)(jax.random.PRNGKey(seed + i)))
     return models
 
 
-def _make_tenants(names, archs, models, args):
-    return [Tenant(n, *models[a], cache_len=max(
-        32, args.prompt_len + args.max_new_tokens + 1), max_batch=4)
-        for n, a in zip(names, archs)]
+def make_tenants(names: Sequence[str], archs: Sequence[str], models, *,
+                 prompt_len: int, max_new_tokens: int) -> list:
+    """Tenants ``names[i]`` serving ``models[archs[i]]``, each with four
+    decode slots of a KV cache long enough for one prompt plus its
+    generated tokens."""
+    cache_len = max(32, prompt_len + max_new_tokens + 1)
+    return [Tenant(n, *models[a], cache_len=cache_len, max_batch=4)
+            for n, a in zip(names, archs)]
 
 
 def _report_line(mode, rep, certify):
@@ -82,7 +140,9 @@ def _report_line(mode, rep, certify):
 
 
 def _run_daemon(names, args, models) -> None:
-    tenants = _make_tenants(names, args.tenants, models, args)
+    tenants = make_tenants(names, args.tenants, models,
+                           prompt_len=args.prompt_len,
+                           max_new_tokens=args.max_new_tokens)
     eng = ServingEngine(tenants, mode="vliw", certify=args.certify,
                         num_devices=args.num_devices,
                         admission_control=args.admission)
@@ -140,6 +200,10 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--tenants", nargs="+", default=["gemma3-1b", "yi-9b"],
                     choices=list(ARCH_IDS))
+    ap.add_argument("--layers", type=int, default=None,
+                    help="serve each registry config at its published "
+                         "widths in bf16 with the depth cut to N layers "
+                         "(default: the reduced float32 smoke config)")
     ap.add_argument("--mode", choices=["time", "batched", "vliw", "all"],
                     default="all")
     ap.add_argument("--requests", type=int, default=4,
@@ -171,7 +235,8 @@ def main() -> None:
                     help="daemon: seconds between live heartbeat lines")
     args = ap.parse_args()
 
-    models = _build_models(args.tenants)
+    enable_compile_cache()
+    models = build_models(args.tenants, args.layers)
     names = [f"t{i}:{a}" for i, a in enumerate(args.tenants)]
 
     if args.daemon:
@@ -187,7 +252,9 @@ def main() -> None:
 
     modes = ["time", "batched", "vliw"] if args.mode == "all" else [args.mode]
     for mode in modes:
-        tenants = _make_tenants(names, args.tenants, models, args)
+        tenants = make_tenants(names, args.tenants, models,
+                               prompt_len=args.prompt_len,
+                               max_new_tokens=args.max_new_tokens)
         # baseline modes define single-device round semantics; the mesh is
         # a vliw-engine feature
         n_dev = args.num_devices if mode == "vliw" else 1

@@ -35,7 +35,11 @@ the device id) and one ``ScheduleTrace``. Ops never coalesce across
 devices. Expert-parallel MoE tenants additionally SPAN the mesh with
 their expert weights when the mesh size divides the expert count; their
 ops stay on the home timeline but carry an all-to-all dispatch/combine
-charge in EDF slack and plan estimates.
+charge in EDF slack and plan estimates. When at least N JAX devices are
+attached, mesh slot d executes on ``jax.devices()[d]``: placement commits
+the tenant's params, KV cache and token slots there, so its packed weights
+and every dispatch follow. A wider mesh than the attached devices is an
+error on a TPU and only modeled (with a warning) on the CPU backend.
 
 Arch-support matrix (which path each tenant takes in vliw mode):
 
@@ -121,6 +125,7 @@ from __future__ import annotations
 import dataclasses
 import math
 import time as _time
+import warnings
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -129,7 +134,8 @@ import numpy as np
 
 from repro.analysis.certify import ScheduleCertifier, check_conservation
 from repro.configs.base import ModelConfig
-from repro.core.costmodel import CostModel, GemmShape, TPUV5E
+from repro.core.costmodel import CostModel, attached_device
+from repro.core.dispatch import device_weight_budget
 from repro.core.jit import (JitStats, KernelProgram, VLIWJit,
                             build_dense_decode_template,
                             build_dense_prefill_template,
@@ -146,6 +152,25 @@ from repro.models.model import Model
 from repro.serving.admission import AdmissionController, DEFAULT_TIERS
 from repro.serving.frontdoor import FrontDoor, MonotonicClock
 from repro.serving.workload import ServeRequest
+
+
+def _slot_devices(n: int) -> Optional[List[Any]]:
+    """The JAX devices an ``n``-slot mesh executes on: the first ``n``
+    attached devices, or None for one slot (the default device). A mesh
+    wider than the attached devices is an error on a TPU; on another
+    backend (the CPU tests) it is only modeled — every slot executes on the
+    default device — and a warning says so."""
+    if n == 1:
+        return None
+    avail = jax.devices()
+    if n <= len(avail):
+        return avail[:n]
+    if avail[0].platform == "tpu":
+        raise ValueError(f"a {n}-device mesh needs {n} chips; "
+                         f"{len(avail)} attached")
+    warnings.warn(f"{n}-device mesh is modeled only: all {n} slots execute "
+                  f"on {avail[0]}", stacklevel=3)
+    return None
 
 
 @dataclasses.dataclass
@@ -397,7 +422,7 @@ class ServingEngine:
                  prefill_declare_min: int = 16,
                  predict_arrivals: bool = False,
                  arrival_alpha: float = 0.2,
-                 weight_budget_bytes: Optional[int] = 1 << 30,
+                 weight_budget_bytes: Optional[int] = None,
                  stacked_layers: bool = True,
                  certify: bool = False,
                  num_devices: int = 1,
@@ -461,7 +486,7 @@ class ServingEngine:
         # token the moment it retires on the modeled clock — the daemon
         # wires the FrontDoor's per-request Ticket delivery here
         self.token_sink = token_sink
-        self.cost = cost or CostModel(TPUV5E)
+        self.cost = cost or CostModel(attached_device())
         # the modeled mesh: N virtual device timelines, each with its own
         # scheduler/coalescer (ops never coalesce across devices) sharing
         # one VLIWJit's plan + weight caches (keyed with the device id).
@@ -481,6 +506,17 @@ class ServingEngine:
             "multi-device serving requires mode='vliw' (baseline modes " \
             "define single-device round semantics)"
         self.placement = PlacementPolicy(self.devices)
+        # the JAX device each mesh slot executes on (None: the default
+        # device runs everything — one slot, or a modeled mesh). A tenant's
+        # params, KV cache and token slots are committed to its home device
+        # when placement binds it, so its packed weights and dispatches
+        # follow them there.
+        self.slot_devices = _slot_devices(len(self.devices))
+        # (id(params), slot) -> (source tree, committed tree): tenants that
+        # share one params tree on one device keep sharing one copy
+        # (operand sharing keys on the tree's identity); holding the source
+        # keeps its id from being recycled
+        self._committed: Dict[Tuple[int, int], Tuple[Any, Any]] = {}
         # per-device timeline/busy vectors of the last vliw run (ServeReport
         # device_time_s / device_busy_s)
         self._last_device_time: Optional[List[float]] = None
@@ -489,13 +525,17 @@ class ServingEngine:
         # templates + block plans); 0 = rebuild per step (baseline).
         # weight_budget_bytes bounds the dispatch executor's packed-weight
         # cache in BYTES — entries are full padded operand copies, and the
-        # stacked per-expert packs of MoE tenants are the big ones
+        # stacked per-expert packs of MoE tenants are the big ones. None
+        # sizes it from the memory of the devices the mesh runs on
+        # (dispatch.device_weight_budget)
         # live_tune=True puts the collaborative autotuner on the dispatch
         # hot path (core/autotuner.LiveTuner): every coalesced group's
         # (bm, bn, bk) is tuned for the group's actual co-resident shapes
         # and flows into the dispatched superkernels, cached per signature
         # in the JIT's tune cache. tune_objective="greedy" is the Table 1
         # ablation (isolated-latency tiles imposed on the shared device).
+        if weight_budget_bytes is None:
+            weight_budget_bytes = device_weight_budget(len(self.devices))
         self.jit = VLIWJit(self.cost, sched_cfg=sched_cfg,
                            max_group=max_group, plan_capacity=plan_capacity,
                            weight_budget_bytes=weight_budget_bytes,
@@ -573,8 +613,8 @@ class ServingEngine:
     # ------------------------------------------------------------------
     # admission
     # ------------------------------------------------------------------
-    def _make_prompt(self, tenant: Tenant, req: ServeRequest,
-                     rng: jax.Array) -> jax.Array:
+    def make_prompt(self, tenant: Tenant, req: ServeRequest,
+                    rng: jax.Array) -> jax.Array:
         """The request's synthetic prompt [1, prompt_len] — derived from
         (rng, req_id) only, so every mode and prefill path sees the exact
         same tokens."""
@@ -598,7 +638,7 @@ class ServingEngine:
         if needs_slot and not slots:
             return 0.0  # caller retries later
         m = tenant.model
-        pbatch = {"tokens": self._make_prompt(tenant, req, rng)}
+        pbatch = {"tokens": self.make_prompt(tenant, req, rng)}
         if m.cfg.arch_type == "vlm":
             pbatch["patch_embeds"] = jnp.zeros(
                 (1, m.cfg.num_patch_tokens, m.cfg.d_model), m.dtype)
@@ -715,7 +755,7 @@ class ServingEngine:
         s = req.prompt_len
         assert s <= t.cache_len, (s, t.cache_len)
         bucket = prefill_bucket(s)
-        prompt = self._make_prompt(t, req, rng)
+        prompt = self.make_prompt(t, req, rng)
         padded = jnp.pad(prompt, ((0, 0), (0, bucket - s)))
         template = self.jit.plan_cache.get_or_build(
             prefill_program_cache_key(t.model, t.params, bucket, t.cache,
@@ -867,10 +907,25 @@ class ServingEngine:
             t = self.tenants[name]
             pl = self.placement.place(name, t.cfg, batch=t.max_batch)
             d = st.tenant_dev[name] = pl.device
+            if self.slot_devices is not None:
+                self._commit(t, d)
             if pl.expert_span > 1:
                 st.sessions[d].set_stream_span(st.stream_ids[name],
                                                pl.expert_span)
         return d
+
+    def _commit(self, t: Tenant, d: int) -> None:
+        """Commit tenant ``t``'s params, KV cache and token slots to mesh
+        slot ``d``'s JAX device (placement time): every array derived from
+        them afterwards — packed weights, activations, logits — lives and
+        computes there."""
+        dev = self.slot_devices[d]
+        key = (id(t.params), d)
+        if key not in self._committed:
+            self._committed[key] = (t.params, jax.device_put(t.params, dev))
+        t.params = self._committed[key][1]
+        t.cache = jax.device_put(t.cache, dev)
+        t.slot_tok = jax.device_put(t.slot_tok, dev)
 
     def _route(self, st: _LoopState, req: ServeRequest) -> int:
         """Append ``req`` to its home device's admission queue."""
@@ -1308,7 +1363,7 @@ class ServingEngine:
             rng: Optional[jax.Array] = None) -> ServeReport:
         rng = rng if rng is not None else jax.random.PRNGKey(0)
         # request identity keys everything downstream — prompt synthesis
-        # (_make_prompt folds req_id into the rng), the scheduler's
+        # (make_prompt folds req_id into the rng), the scheduler's
         # per-request eviction dedup, and the certifier's conservation
         # check — so a trace with colliding ids must be rejected up front
         # instead of silently double-counting one identity

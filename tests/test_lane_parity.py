@@ -1,34 +1,35 @@
-"""Interpret-vs-compiled Pallas lane parity (PR 9 satellite).
+"""Interpret-vs-compiled Pallas lane parity.
 
-The compiled lane (``REPRO_PALLAS_INTERPRET=0``) is the wall-clock regime
-every perf claim is measured in; interpret mode is the correctness regime
-CI runs everywhere. These tests pin the contract between them: at pow2
+The compiled lane (every Pallas call on a TPU backend) is the wall-clock
+regime every perf claim is measured in; interpret mode is the correctness
+regime CI runs everywhere. These tests pin the contract between them: at pow2
 dims — where the tuned pow2 ``bk`` equals K and both lanes reduce in one
 k-step — outputs are BIT-identical; when ``bk`` splits K the compiled
 MXU may reassociate the partial-sum adds, so parity is within a documented
 last-ulp tolerance instead.
 
-Skips wholesale on hosts without a usable compiled lane (CPU jaxlib:
-``Only interpret mode is supported on CPU backend``) via the same
-``compiled_lane_available()`` probe the benches and CI gate on.
+Each test skips unless the backend is a TPU (Pallas only interprets on
+CPU: ``Only interpret mode is supported on CPU backend``); the check runs
+inside the test, never while the module is imported.
 """
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import repro.kernels.ops as kops
 from repro.kernels import coalesced_gemm, coalesced_gemv, flash_attention
 from repro.kernels.ops import execute_superkernel, pack_problems
-
-pytestmark = pytest.mark.skipif(
-    not kops.compiled_lane_available(),
-    reason="no compiled Pallas lane on this host (interpret-only backend)")
 
 # one k-step (bk == K): both lanes reduce identically -> bit parity
 EXACT = dict(rtol=0, atol=0)
 # bk < K splits the reduction; compiled MXU may reassociate partial sums
 SPLIT_TOL = dict(rtol=1e-6, atol=1e-6)
+
+
+def _require_tpu():
+    if jax.default_backend() != "tpu":
+        pytest.skip(f"no compiled Pallas lane on the "
+                    f"{jax.default_backend()} backend")
 
 
 def _problems(rng, g, m, n, k, dtype=jnp.float32):
@@ -41,6 +42,7 @@ def _problems(rng, g, m, n, k, dtype=jnp.float32):
 @pytest.mark.parametrize("shared", [False, True],
                          ids=["grouped", "shared-operand"])
 def test_superkernel_parity_pow2(rng, shared):
+    _require_tpu()
     probs = _problems(rng, 3, 16, 256, 256)
     if shared:
         w = probs[0][1]
@@ -55,6 +57,7 @@ def test_superkernel_parity_pow2(rng, shared):
 
 def test_coalesced_gemm_parity_bk_split(rng):
     """bk=128 over K=512: four-step reduction, documented tolerance."""
+    _require_tpu()
     probs = _problems(rng, 2, 32, 128, 512)
     packed = pack_problems(probs, bm=32)
     args = (packed.a_packed, packed.b_stacked, packed.group_ids)
@@ -64,6 +67,7 @@ def test_coalesced_gemm_parity_bk_split(rng):
 
 
 def test_coalesced_gemv_parity(rng):
+    _require_tpu()
     k1, k2 = jax.random.split(rng)
     x = jax.random.normal(k1, (4, 256), jnp.float32)
     w = jax.random.normal(k2, (4, 256, 128), jnp.float32)
@@ -77,6 +81,7 @@ def test_flash_attention_parity(rng, causal):
     """Both lanes run the SAME online-softmax recurrence over identical
     kv-block ordering, so parity is exact at one kv step and last-ulp
     across splits; we pin the split case at the documented tolerance."""
+    _require_tpu()
     k1, k2, k3 = jax.random.split(rng, 3)
     q = jax.random.normal(k1, (2, 256, 64), jnp.float32)
     k = jax.random.normal(k2, (2, 256, 64), jnp.float32)
@@ -91,6 +96,7 @@ def test_flash_attention_parity(rng, causal):
 def test_stacked_scan_parity(rng):
     """The layer-stacked regime: scan-over-layers drives the same
     coalesced_gemm body once per layer with a fresh weight slice."""
+    _require_tpu()
     L, m, k = 3, 16, 256
     ka, kw = jax.random.split(rng)
     a = jax.random.normal(ka, (m, k), jnp.float32)
