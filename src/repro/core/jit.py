@@ -129,6 +129,7 @@ from repro.core.plancache import PlanCache, PlanCacheStats
 from repro.core.scheduler import OoOScheduler, SchedulerConfig
 from repro.core.schedtrace import (DispatchRecord, OpRecord, ProgramAdmit,
                                    ScheduleTrace)
+from repro.core.spans import span, spanned
 from repro.kernels.coalesced_gemm import coalesced_gemm
 from repro.models.layers import rmsnorm, apply_rope
 
@@ -261,6 +262,12 @@ def _scan_gemm(a: jax.Array, w_pad: jax.Array, n_real: int, *, bm: int,
 
 def _stack_slice(arr: jax.Array, lo: int, hi: int) -> jax.Array:
     return arr if lo == 0 and hi == int(arr.shape[0]) else arr[lo:hi]
+
+
+def _rename(fn: Callable, name: str) -> None:
+    """Give ``fn`` the name ``name``: ``jax.jit`` names its executable
+    ``jit_<name>``, which is what the device trace's module line shows."""
+    fn.__name__ = fn.__qualname__ = name
 
 
 @dataclasses.dataclass
@@ -696,11 +703,11 @@ def _jitted_decode_attend(cfg: ModelConfig, B: int, is_global: bool,
            jnp.dtype(out_dtype).name)
     fn = _GLUE_JITS.get(key)
     if fn is None:
-        def attend(q, k, v, kc, vc, pos):
+        def decode_attend(q, k, v, kc, vc, pos):
             return _gqa_decode_attend(cfg, B, q, k, v, kc, vc, pos,
                                       is_global, out_dtype)
 
-        fn = _GLUE_JITS[key] = jax.jit(attend)
+        fn = _GLUE_JITS[key] = jax.jit(decode_attend)
     return fn
 
 
@@ -710,11 +717,11 @@ def _jitted_prefill_attend(cfg: ModelConfig, Sp: int, is_global: bool,
            jnp.dtype(out_dtype).name)
     fn = _GLUE_JITS.get(key)
     if fn is None:
-        def attend(q, k, v, positions):
+        def prefill_attend(q, k, v, positions):
             return _causal_prefill_attend(cfg, Sp, q, k, v, positions,
                                           is_global, out_dtype)
 
-        fn = _GLUE_JITS[key] = jax.jit(attend)
+        fn = _GLUE_JITS[key] = jax.jit(prefill_attend)
     return fn
 
 
@@ -726,7 +733,7 @@ def _jitted_moe_route(cfg: ModelConfig, B: int, C: int) -> Callable:
     if fn is None:
         E, top_k, d = mcfg.num_experts, mcfg.top_k, cfg.d_model
 
-        def route_dispatch(router_p, h2):
+        def moe_route_dispatch(router_p, h2):
             weights, experts, _aux = moe_lib.route(router_p, h2, mcfg)
             xg = h2.reshape(1, B, d)
             wgt = weights.reshape(1, B, top_k)
@@ -736,7 +743,7 @@ def _jitted_moe_route(cfg: ModelConfig, B: int, C: int) -> Callable:
                     xx, ww, ee, E, top_k, C))(xg, wgt, eg)
             return buf, meta, wgt
 
-        fn = _GLUE_JITS[key] = jax.jit(route_dispatch)
+        fn = _GLUE_JITS[key] = jax.jit(moe_route_dispatch)
     return fn
 
 
@@ -747,12 +754,12 @@ def _jitted_moe_combine(cfg: ModelConfig, B: int) -> Callable:
     if fn is None:
         d = cfg.d_model
 
-        def combine(out_buf, wgt, meta):
+        def moe_combine(out_buf, wgt, meta):
             return jax.vmap(
                 lambda ob, ww, mm: moe_lib.combine_tokens(
                     ob, ww.reshape(-1), mm, B, d))(out_buf, wgt, meta)
 
-        fn = _GLUE_JITS[key] = jax.jit(combine)
+        fn = _GLUE_JITS[key] = jax.jit(moe_combine)
     return fn
 
 
@@ -763,11 +770,11 @@ def _jitted_ssm_core(cfg: ModelConfig) -> Callable:
     if fn is None:
         scfg, d = cfg.ssm, cfg.d_model
 
-        def core(mamba_p, zxbcdt, conv, h):
+        def ssm_decode_core(mamba_p, zxbcdt, conv, h):
             return ssm_lib.decode_core(mamba_p, zxbcdt,
                                        {"conv": conv, "h": h}, scfg, d)
 
-        fn = _GLUE_JITS[key] = jax.jit(core)
+        fn = _GLUE_JITS[key] = jax.jit(ssm_decode_core)
     return fn
 
 
@@ -910,6 +917,9 @@ def _stacked_dense_body_stage(model, params, B: int, lo: int, hi: int, *,
             xs = dict(aux, kc=kc_full[lo:hi], vc=vc_full[lo:hi], w=w)
             return jax.lax.scan(body, x, xs)
 
+        # one body serves dense and MoE decode; each keeps its own name
+        _rename(scan_fn, "moe_decode_layers" if moe
+                else "dense_decode_layers")
         return scan_fn
 
     aux = {"ln1": ln1s, "ln2": ln2s}
@@ -1209,7 +1219,7 @@ def _build_stacked_ssm_decode_template(model, params, batch: int
         # codegens embedded constants differently in the last ulp than
         # traced arguments, which would break bit-identity with the
         # per-layer oracle's jitted decode_core glue
-        def scan_fn(x, conv_full, h_full, w, aux):
+        def ssm_decode_layers(x, conv_full, h_full, w, aux):
             def body(carry, per):
                 hh = rmsnorm(carry, per["ln1"], eps)
                 zxbcdt = gemm(hh, per["w"]["ssm_in_proj"], n_in)
@@ -1222,7 +1232,7 @@ def _build_stacked_ssm_decode_template(model, params, batch: int
             xs = dict(aux, conv=conv_full, h=h_full, w=w)
             return jax.lax.scan(body, x, xs)
 
-        return scan_fn
+        return ssm_decode_layers
 
     aux = {"ln1": ln1s, "mamba": mamba_rest}
 
@@ -1438,7 +1448,7 @@ def _stacked_prefill_body_stage(model, params, Sp: int, lo: int, hi: int
                               interpret=interpret)
 
         # per-layer norms enter as jit arguments (see the decode body note)
-        def scan_fn(x, positions, w, aux):
+        def dense_prefill_layers(x, positions, w, aux):
             def body(carry, per):
                 wl = per["w"]
                 h = rmsnorm(carry, per["ln1"], eps)
@@ -1457,7 +1467,7 @@ def _stacked_prefill_body_stage(model, params, Sp: int, lo: int, hi: int
             xs = dict(aux, w=w)
             return jax.lax.scan(body, x, xs)
 
-        return scan_fn
+        return dense_prefill_layers
 
     aux = {"ln1": ln1s, "ln2": ln2s}
 
@@ -1723,6 +1733,12 @@ class JitStats:
     # device (a mesh where one device never coalesces is misplaced).
     collective_time_s: float = 0.0
     coalesced_groups: int = 0
+    # decode programs the serving engine admitted, their active rows, and
+    # the real seconds (time.monotonic) from each one's admission to the
+    # end of the token sync that lands its tokens on the host
+    decode_programs: int = 0
+    decode_rows: int = 0
+    decode_step_s: StreamStat = dataclasses.field(default_factory=StreamStat)
 
     @property
     def mean_group(self) -> float:
@@ -2026,6 +2042,7 @@ class JitSession:
             else:
                 self._push_op(prog, nxt)
 
+    @spanned("jit.tick")
     def tick(self, now: float) -> TickEvent:
         """Execute one scheduler decision at virtual time ``now``."""
         self._sync_cache_stats()
@@ -2033,7 +2050,8 @@ class JitSession:
         if not self.live:
             return TickEvent("idle", now, completed=completed)
         self._started = True
-        decision = self.sched.decide(now)
+        with span(self.jit.spans, "sched.decide"):
+            decision = self.sched.decide(now)
         self.stats.evictions = self.sched.evictions
         self._sync_cache_stats()
         if decision.kind == "wait":
@@ -2075,10 +2093,11 @@ class JitSession:
             # the jitted dispatch fast path (core/dispatch.py): persistent
             # packed weights + bucketed envelopes + compiled
             # pack/kernel/unpack
-            outs = self.jit.executor.execute(plan.ops,
-                                             shared_operand=shared,
-                                             device=self.device,
-                                             block=tuned_block)
+            with span(self.jit.spans, "dispatch.execute"):
+                outs = self.jit.executor.execute(plan.ops,
+                                                 shared_operand=shared,
+                                                 device=self.device,
+                                                 block=tuned_block)
             serial_shapes = [o.shape for o in plan.ops]
             t = self.cost.coalesced_time(serial_shapes, plan.block,
                                          shared_operand=shared) + coll
@@ -2168,6 +2187,10 @@ class VLIWJit:
             wcap, byte_capacity=device_weight_budget()
             if weight_budget_bytes is None else weight_budget_bytes)
         self.executor = SuperkernelExecutor(self.weight_cache, bm=bm)
+        # host spans in the profiler's trace around the tick, the
+        # scheduler's decision and the dispatch, and around the serving
+        # engine's calls (core/spans.py); off, no span is constructed
+        self.spans = False
 
     def session(self, record_trace: bool = False, *, device: int = 0,
                 cost: Optional[CostModel] = None,

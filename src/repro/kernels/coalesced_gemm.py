@@ -77,5 +77,6 @@ def coalesced_gemm(a_packed: jax.Array, b_stacked: jax.Array,
         out_shape=jax.ShapeDtypeStruct((M, N), a_packed.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="coalesced_gemm",
         interpret=interpret_default() if interpret is None else interpret,
     )(group_ids, a_packed, b_stacked)

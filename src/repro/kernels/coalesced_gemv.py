@@ -67,6 +67,7 @@ def coalesced_gemv(x: jax.Array, w: jax.Array, *, bn: int = 128,
         out_shape=jax.ShapeDtypeStruct((G, _SUBLANES, N), x.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="coalesced_gemv",
         interpret=interpret_default() if interpret is None else interpret,
     )(xs, w)
     return out[:, 0, :]

@@ -95,5 +95,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct((BH, S, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="flash_attention",
         interpret=interpret_default() if interpret is None else interpret,
     )(q, k, v)

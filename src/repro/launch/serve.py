@@ -172,9 +172,12 @@ def _run_daemon(names, args, models) -> None:
         door.close()
 
     def heartbeat(stats) -> None:
+        wait = stats["queue_wait_p50_s"]
+        wait = "-" if wait is None else f"{wait * 1e3:.1f}ms"
         print(f"  [t={stats['t']:6.2f}s] submitted={stats['submitted']:4d} "
               f"finished={stats['finished']:4d} shed={stats['shed']:3d} "
-              f"inflight={stats['inflight']} waiting={stats['waiting']}")
+              f"inflight={stats['inflight']} waiting={stats['waiting']} "
+              f"queue_wait_p50={wait}")
 
     print(f"daemon: {len(names)} tenants, {args.rate:.0f} req/s open-loop "
           f"for {args.duration:.1f}s, admission="

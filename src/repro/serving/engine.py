@@ -119,6 +119,21 @@ requests never occupy a slot; they count as SLO misses in
 vanishing into ``unfinished``). The same admission path runs under
 ``run`` for deterministic open-loop replay benches
 (benchmarks/e2e_slo_attainment.py gates admission-on vs admit-everything).
+
+Real time, beside the modeled clock
+-----------------------------------
+
+Every time above is on the modeled device clock. What the engine measures
+on the host's monotonic clock is kept apart: each ``ServeRequest`` gets
+``released_s`` (taken from the door, or routed in a replay),
+``admitted_s`` (off the waiting list into a prefill) and
+``first_token_s`` (first token on the host); ``JitStats`` counts the
+decode programs admitted, their active rows, and ``decode_step_s``, the
+real time from a decode program's admission to the end of the token sync
+that lands it. ``spans = True`` puts named host spans around the device
+pass, the token sync, admissions, the prefill landing, retirement, the
+door's poll, the idle wait, and the JIT's tick, decision and dispatch
+into the profiler's trace (``core/spans.py``).
 """
 from __future__ import annotations
 
@@ -147,6 +162,7 @@ from repro.core.jit import (JitStats, KernelProgram, VLIWJit,
 from repro.core.kernelspec import gemm_population
 from repro.core.scheduler import SchedulerConfig
 from repro.core.schedtrace import ScheduleTrace
+from repro.core.spans import span, spanned
 from repro.distributed.placement import DeviceSet, PlacementPolicy
 from repro.models.model import Model
 from repro.serving.admission import AdmissionController, DEFAULT_TIERS
@@ -404,6 +420,9 @@ class _LoopState:
     pis: List[int]
     waiting: List[List[ServeRequest]]
     inflight: Dict[str, Any]
+    # tenant -> time.monotonic() at which its inflight decode program was
+    # admitted (JitStats.decode_step_s)
+    decode_admitted: Dict[str, float]
     now: List[float]                     # per-device virtual clocks
     busy: List[float]                    # analytic charges per device
     committed: List[float]               # admission-committed horizon
@@ -548,6 +567,17 @@ class ServingEngine:
             t.slot_tok = jnp.zeros((t.max_batch, 1), jnp.int32)
             t.slot_remaining = [0] * t.max_batch
 
+    @property
+    def spans(self) -> bool:
+        """Host spans in the profiler's trace around the engine's calls,
+        the JIT's tick, scheduler decision and dispatch, the door's poll
+        and the idle wait (core/spans.py). Off by default."""
+        return self.jit.spans
+
+    @spans.setter
+    def spans(self, on: bool) -> None:
+        self.jit.spans = bool(on)
+
     # ------------------------------------------------------------------
     # modeled step times
     # ------------------------------------------------------------------
@@ -622,6 +652,7 @@ class ServingEngine:
                                   (1, req.prompt_len), 0,
                                   tenant.cfg.vocab_size)
 
+    @spanned("engine.admit_prefill")
     def _admit(self, tenant: Tenant, req: ServeRequest, rng: jax.Array,
                now: float) -> float:
         """Prefill ``req`` into the tenant. Returns the modeled prefill time
@@ -637,6 +668,7 @@ class ServingEngine:
         slots = [i for i, r in enumerate(tenant.slot_req) if r is None]
         if needs_slot and not slots:
             return 0.0  # caller retries later
+        req.admitted_s = _time.monotonic()
         m = tenant.model
         pbatch = {"tokens": self.make_prompt(tenant, req, rng)}
         if m.cfg.arch_type == "vlm":
@@ -648,9 +680,11 @@ class ServingEngine:
         logits, pc = m.prefill(tenant.params, pbatch,
                                cache_len=tenant.cache_len)
         tok = jnp.argmax(logits[0, -1]).astype(jnp.int32)
-        req.tokens_out = [int(tok)]
+        first = int(tok)
+        req.tokens_out = [first]
         dt = self._prefill_time(m.cfg, req.prompt_len)
-        self._emit_token(req, int(tok), now + dt)
+        req.first_token_s = _time.monotonic()
+        self._emit_token(req, first, now + dt)
         if not needs_slot:
             req.finish_t = now + dt    # done at admission: no decode steps
             return dt
@@ -692,6 +726,7 @@ class ServingEngine:
         self._consume(t, logits, now + dt)
         return dt
 
+    @spanned("engine.token_sync")
     def _consume(self, t: Tenant, logits: jax.Array, now: float = 0.0
                  ) -> None:
         toks = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
@@ -702,6 +737,7 @@ class ServingEngine:
             self._emit_token(req, int(toks[slot]), now)
             t.slot_remaining[slot] -= 1
 
+    @spanned("engine.retire")
     def _retire(self, t: Tenant, now: float) -> List[ServeRequest]:
         """Free slots of finished requests; returns the retired requests
         (the vliw trace records their ids, everyone else just counts)."""
@@ -732,6 +768,7 @@ class ServingEngine:
         return self.declared_prefill and t.cfg.arch_type == "dense" \
             and self._jit_capable(t)
 
+    @spanned("engine.declare_prefill")
     def _declare_prefill(self, t: Tenant, req: ServeRequest, rng: jax.Array,
                          stream_id: int, now: float
                          ) -> Optional[KernelProgram]:
@@ -781,17 +818,21 @@ class ServingEngine:
         if needs_slot:
             t.slot_req[slot] = req
             t.slot_remaining[slot] = req.max_new_tokens - 1
+        req.admitted_s = _time.monotonic()
         return prog
 
+    @spanned("engine.prefill_land")
     def _on_prefill_complete(self, t: Tenant, prog: KernelProgram,
                              now: float) -> Tuple[float, int]:
         """Land a completed prefill: first token, KV slot state, traffic
         charge. Returns (now, requests retired here)."""
         req: ServeRequest = prog.env["req"]
         tok = jnp.argmax(prog.env["logits"][0]).astype(jnp.int32)
-        req.tokens_out = [int(tok)]
+        first = int(tok)
+        req.tokens_out = [first]
         now += self._prefill_attn_time(t.cfg, prog.env["real_len"])
-        self._emit_token(req, int(tok), now)
+        req.first_token_s = _time.monotonic()
+        self._emit_token(req, first, now)
         slot = prog.env["slot"]
         if slot is None:
             req.finish_t = now     # single token: done at prefill, no slot
@@ -800,6 +841,7 @@ class ServingEngine:
         t.slot_tok = t.slot_tok.at[slot, 0].set(tok)
         return now, 0
 
+    @spanned("engine.build_program")
     def _build_program(self, t: Tenant, stream_id: int, now: float
                        ) -> KernelProgram:
         """Bind the tenant's next decode step, carrying the tightest
@@ -894,7 +936,7 @@ class ServingEngine:
                         for n, p in self.placement.assignments.items()},
             queues=[[] for _ in range(n_dev)], pis=[0] * n_dev,
             waiting=[[] for _ in range(n_dev)], inflight={},
-            now=[0.0] * n_dev, busy=[0.0] * n_dev,
+            decode_admitted={}, now=[0.0] * n_dev, busy=[0.0] * n_dev,
             committed=[0.0] * n_dev, oracle=oracle, next_hint=next_hint)
 
     def _dev_of(self, st: _LoopState, name: str) -> int:
@@ -929,6 +971,7 @@ class ServingEngine:
 
     def _route(self, st: _LoopState, req: ServeRequest) -> int:
         """Append ``req`` to its home device's admission queue."""
+        req.released_s = _time.monotonic()
         d = self._dev_of(st, req.tenant)
         st.queues[d].append(req)
         st.total += 1
@@ -959,6 +1002,7 @@ class ServingEngine:
         st.committed[d] = max(st.committed[d], st.now[d]) + cost_s
         return True
 
+    @spanned("engine.device_pass")
     def _device_pass(self, st: _LoopState, d: int) -> bool:
         """One pass over device ``d``'s timeline: drain due arrivals
         (through the admission controller when the front door is on),
@@ -1049,7 +1093,10 @@ class ServingEngine:
                                            now[d])
                 if t.cfg.arch_type in ("moe", "ssm"):
                     session.stats.nondense_programs += 1
+                session.stats.decode_programs += 1
+                session.stats.decode_rows += len(t.active_slots())
                 st.inflight[name] = prog
+                st.decode_admitted[name] = _time.monotonic()
                 session.admit(prog)
                 progressed = True
 
@@ -1088,6 +1135,8 @@ class ServingEngine:
                                    max(len(t.active_slots()), 1))
             self._consume(t, prog.env["logits"][:, None, :],
                           now[d] + attn)
+            session.stats.decode_step_s.add(
+                _time.monotonic() - st.decode_admitted.pop(t.name))
             now[d] += attn
             busy[d] += attn
             retired = self._retire(t, now[d])
@@ -1199,6 +1248,13 @@ class ServingEngine:
     # ------------------------------------------------------------------
     def _live_stats(self, st: _LoopState, served: List[ServeRequest],
                     t: float) -> Dict[str, Any]:
+        """The daemon's heartbeat. ``waiting`` is the length of the
+        waiting lists; ``queue_wait_p50_s`` the median real time from
+        release to admission of the requests admitted in the last second
+        (None if none was)."""
+        since = _time.monotonic() - 1.0
+        waits = [r.admitted_s - r.released_s for r in served
+                 if r.admitted_s >= since]
         return {
             "t": t,
             "submitted": len(served),
@@ -1207,7 +1263,7 @@ class ServingEngine:
             "shed": sum(1 for r in served if r.shed),
             "inflight": len(st.inflight),
             "waiting": sum(len(w) for w in st.waiting),
-            "device_time_s": list(st.now),
+            "queue_wait_p50_s": float(np.median(waits)) if waits else None,
         }
 
     def serve_forever(self, door: FrontDoor, *,
@@ -1263,7 +1319,9 @@ class ServingEngine:
                     # modeled timeline is floored at real elapsed time
                     for d in range(n_dev):
                         st.now[d] = max(st.now[d], now_r)
-                for req in door.poll(now_r):
+                with span(self.jit.spans, "door.poll"):
+                    released = door.poll(now_r)
+                for req in released:
                     if req.req_id in seen_ids:
                         raise ValueError(
                             f"duplicate req_id {req.req_id} through the "
@@ -1316,8 +1374,9 @@ class ServingEngine:
                     if door.close_at is not None \
                             and door.close_at > now_r:
                         targets.append(door.close_at)
-                    clock.sleep_until(min(targets) if targets
-                                      else now_r + idle_poll_s)
+                    with span(self.jit.spans, "engine.idle_wait"):
+                        clock.sleep_until(min(targets) if targets
+                                          else now_r + idle_poll_s)
         finally:
             self.token_sink = prev_sink
         self._close_loop(st, served)
@@ -1381,9 +1440,11 @@ class ServingEngine:
         # returned report, and the caller's trace objects are NEVER
         # mutated — a trace can be replayed across engines and modes
         # without the defensive deepcopy every call site used to need
+        nan = float("nan")
         requests = [dataclasses.replace(
-            r, finish_t=float("nan"), tokens_out=None, shed=False,
-            degraded_from=None) for r in trace]
+            r, finish_t=nan, tokens_out=None, shed=False,
+            degraded_from=None, released_s=nan, admitted_s=nan,
+            first_token_s=nan) for r in trace]
         pending = sorted(requests, key=lambda r: r.arrival_t)
         wall0 = _time.perf_counter()
         if self.mode == "vliw":

@@ -27,6 +27,13 @@ class ServeRequest:
     tokens_out: Optional[List[int]] = None
     shed: bool = False
     degraded_from: Optional[int] = None
+    # the request's path through the engine on the host's monotonic clock
+    # (time.monotonic, never the modeled device clock); NaN until reached:
+    # taken from the door (routed, in a replay), off the waiting list into
+    # a prefill, first token on the host
+    released_s: float = float("nan")
+    admitted_s: float = float("nan")
+    first_token_s: float = float("nan")
 
     @property
     def latency(self) -> float:
