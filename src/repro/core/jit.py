@@ -84,9 +84,9 @@ and plan/weight-cache entries become O(1) in depth. Design points:
     updates, which the epilogue concatenates back into the tenant's cache
     — the same [L, ...] layout the per-layer path's ``jnp.stack`` built;
   * the scan body's GEMMs (``_scan_gemm``) replicate the dispatch
-    executor's solo-dispatch bucketing EXACTLY (same m-tile bucket, same
-    power-of-two envelopes, same block sizes), which is what makes the
-    stacked path bit-identical to per-layer emission
+    executor's solo-dispatch bucketing EXACTLY at the tile they are given
+    (same m-tile bucket, same power-of-two envelopes, same bk), which is
+    what makes the stacked path bit-identical to per-layer emission
     (tests/test_stacked_templates.py asserts logits AND cache identity
     for dense decode/prefill, MoE and SSM);
   * cost/coalescing granularity: a stacked op is charged as L sequential
@@ -104,6 +104,7 @@ admission timing (tests/test_event_loop.py).
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import itertools
 import math
@@ -131,6 +132,7 @@ from repro.core.schedtrace import (DispatchRecord, OpRecord, ProgramAdmit,
                                    ScheduleTrace)
 from repro.core.spans import span, spanned
 from repro.kernels.coalesced_gemm import coalesced_gemm
+from repro.kernels.ops import check_vmem
 from repro.models.layers import rmsnorm, apply_rope
 
 
@@ -227,9 +229,11 @@ class StackedGemmStage:
     operands: List[StackedOperand]
     layers: int                    # hi - lo
     # run(env, {operand tag -> padded stacked array}, executor): executes
-    # the scan and writes results (residual stream, cache updates) to env
+    # the scan and writes results (residual stream, cache updates) to env;
+    # a prefill body returns its (Sp, bm, bn, bk) for
+    # JitStats.prefill_bodies, every other body None
     run: Callable[[Dict[str, Any], Dict[str, jax.Array],
-                   SuperkernelExecutor], None]
+                   SuperkernelExecutor], Optional[Tuple[int, ...]]]
     # declared access sets (see GemmStage.reads/writes): a scanned body
     # reads the residual stream + cache slices and writes the residual
     # stream + its cache-update chunk
@@ -246,10 +250,12 @@ _PROG_UIDS = itertools.count(1)
 def _scan_gemm(a: jax.Array, w_pad: jax.Array, n_real: int, *, bm: int,
                bn: int, bk: int, interpret: bool) -> jax.Array:
     """One GEMM inside a scanned layer body, replicating the dispatch
-    executor's solo dispatch EXACTLY — same m-tile bucket, same padded
-    (K, N) envelope (``w_pad`` is one xs slice of a cached
-    ``stacked_operand``), same block clamping — so a stacked body is
-    bit-identical to the per-layer path dispatching each stage."""
+    executor's solo dispatch EXACTLY at the tile it is given — same m-tile
+    bucket, same padded (K, N) envelope (``w_pad`` is one xs slice of a
+    cached ``stacked_operand``), same block clamping — so a stacked body
+    is bit-identical to the per-layer path dispatching each stage at that
+    tile. Every output element sums its K blocks in bk order, so tiles
+    that share bk give the same bits."""
     m = int(a.shape[0])
     K, N = int(w_pad.shape[-2]), int(w_pad.shape[-1])
     m_tiles = _tile_bucket([m], bm)
@@ -1375,6 +1381,23 @@ def prefill_bucket(prompt_len: int, minimum: int = 8) -> int:
     return max(minimum, 1 << (prompt_len - 1).bit_length())
 
 
+# The stacked prefill body's GEMM tile. At the executor's decode-sized tile
+# (bm 8, bn 128) a prompt GEMM runs one Pallas grid step per 1 MFLOP and
+# re-streams each weight panel once per 8 rows; tiled by its own rows it
+# streams each panel Sp / bm times. bk stays the executor's, so the K
+# blocks are summed in the same order. (512, 512) timed fastest of bm and
+# bn in {256, 512} on a TPU v5e at buckets 512-2048 (PERF.md §6).
+PREFILL_BM_MAX = 512
+PREFILL_BN = 512
+
+
+def prefill_tile(m: int, bk: int) -> Tuple[int, int, int]:
+    """GEMM tile (bm, bn, bk) of a prefill body over ``m`` rows (its
+    bucket, a power of two): min(m, PREFILL_BM_MAX) rows and PREFILL_BN
+    columns a tile, at the executor's ``bk``."""
+    return min(m, PREFILL_BM_MAX), PREFILL_BN, bk
+
+
 def _causal_prefill_attend(cfg: ModelConfig, Sp: int, q_flat, k_flat,
                            v_flat, positions, is_global: bool, out_dtype
                            ) -> Tuple[jax.Array, jax.Array, jax.Array]:
@@ -1472,18 +1495,25 @@ def _stacked_prefill_body_stage(model, params, Sp: int, lo: int, hi: int
     aux = {"ln1": ln1s, "ln2": ln2s}
 
     def run(env, padded, ex, block=None):
-        # live-tuned tile override (JitSession._run_stacked): keyed beside
-        # the executor defaults, so each distinct tuned config compiles
-        # its scan body once and stable configs never retrace
-        key = (ex.bm, ex.bn, ex.bk, ex.interpret) if block is None else \
-            (block.bm, block.bn, block.bk, ex.interpret)
+        # the live-tuned tile (JitSession._run_stacked) overrides the
+        # bucket's own; each distinct tile compiles the scan body once
+        tile = prefill_tile(Sp, ex.bk) if block is None else \
+            (block.bm, block.bn, block.bk)
+        key = tile + (ex.interpret,)
         fn = jits.get(key)
         if fn is None:
+            bm, bn, bk = tile
+            for w in padded.values():
+                K, N = int(w.shape[-2]), int(w.shape[-1])
+                check_vmem(bm, min(bn, N), min(bk, K),
+                           dtype_bytes=w.dtype.itemsize,
+                           interpret=ex.interpret)
             fn = jits[key] = jax.jit(make_scan(*key))
         x, (k_ys, v_ys) = fn(env["x"], env["positions"], padded, aux)
         env["x"] = x
         env["new_layers"]["k"].append(k_ys)
         env["new_layers"]["v"].append(v_ys)
+        return (Sp,) + tile
 
     return StackedGemmStage(
         tag=f"body_{lo}_{hi}",
@@ -1739,6 +1769,11 @@ class JitStats:
     decode_programs: int = 0
     decode_rows: int = 0
     decode_step_s: StreamStat = dataclasses.field(default_factory=StreamStat)
+    # stacked prefill bodies dispatched, by (Sp, bm, bn, bk): the prompt
+    # bucket and the GEMM tile its body ran at (``prefill_tile``). A
+    # Counter: ``+`` merges, ``-`` gives an epoch's delta
+    prefill_bodies: collections.Counter = dataclasses.field(
+        default_factory=collections.Counter)
 
     @property
     def mean_group(self) -> float:
@@ -2034,7 +2069,9 @@ class JitSession:
                 else:
                     ex.stats.weight_hits += 1
                 ex.stats.dispatches += 1
-            st.run(prog.env, padded, ex, block)
+            prefill = st.run(prog.env, padded, ex, block)
+            if prefill is not None:
+                self.stats.prefill_bodies[prefill] += 1
             prog.pc += 1
             nxt = prog.advance_glue()
             if nxt is None:

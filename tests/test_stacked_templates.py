@@ -14,12 +14,12 @@ import numpy as np
 import pytest
 
 from repro.configs import smoke_config
-from repro.core.jit import (StackedGemmStage, VLIWJit,
-                            build_dense_decode_template,
+from repro.core.jit import (PREFILL_BM_MAX, PREFILL_BN, StackedGemmStage,
+                            VLIWJit, build_dense_decode_template,
                             build_dense_prefill_template,
                             build_moe_decode_template,
                             build_ssm_decode_template, partition_layers,
-                            prefill_bucket)
+                            prefill_bucket, prefill_tile)
 from repro.models import Model
 from repro.serving import ServeRequest, ServingEngine, Tenant
 
@@ -90,8 +90,10 @@ def test_stacked_decode_bit_identical_to_per_layer(fam, batch, models):
             np.asarray(want_cache["layers"][leaf]), err_msg=leaf)
 
 
-@pytest.mark.parametrize("prompt_len", [5, 12])
+@pytest.mark.parametrize("prompt_len", [5, 12, 100])
 def test_stacked_prefill_bit_identical_to_per_layer(prompt_len, models):
+    """Buckets 8, 16 and 128: the stacked body runs ``prefill_tile``, the
+    per-layer path the executor's tile, at the same bk."""
     m, params = models["dense"]
     cfg = m.cfg
     Sp = prefill_bucket(prompt_len)
@@ -100,7 +102,7 @@ def test_stacked_prefill_bit_identical_to_per_layer(prompt_len, models):
     padded = jnp.pad(toks, ((0, 0), (0, Sp - prompt_len)))
     outs = {}
     for stacked in (True, False):
-        cache = m.init_cache(2, 32)
+        cache = m.init_cache(2, max(32, Sp))
         tmpl = build_dense_prefill_template(m, params, Sp, stacked=stacked)
         prog = tmpl.bind(stream_id=0, tokens=padded, cache=cache,
                          env_extra={"real_len": prompt_len, "slot": 1})
@@ -189,6 +191,45 @@ def test_stacked_hot_swap_trips_guard(models):
                                   max_batch=2)], mode="vliw")
     rep_fresh = fresh.run(trace2)
     assert _tokens(rep_swapped) == _tokens(rep_fresh)
+
+
+# ---------------------------------------------------------------------------
+# the prefill body's GEMM tile
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("m", [8, 16, 64, 512, 2048])
+def test_prefill_tile_by_rows(m):
+    """A prompt bucket takes its own rows (up to PREFILL_BM_MAX),
+    PREFILL_BN columns and the executor's bk."""
+    assert prefill_tile(m, 512) == (min(m, PREFILL_BM_MAX), PREFILL_BN, 512)
+
+
+def test_engine_counts_prefill_bodies_by_bucket():
+    """``JitStats.prefill_bodies`` gets one entry per bucket served, its
+    counts sum to the prefills admitted (prompts shorter than
+    ``prefill_declare_min`` are admitted without one), and ``-`` of two
+    readings gives the epoch's own."""
+    cfg = smoke_config("yi-9b")
+    assert len(partition_layers(cfg.global_layer_flags())) == 1
+    m = Model(cfg, param_dtype=jnp.float32)
+    eng = ServingEngine([Tenant("a", m, m.init(jax.random.PRNGKey(5)),
+                                cache_len=136, max_batch=2)], mode="vliw")
+    ex = eng.jit.executor
+
+    def entry(Sp):
+        return (Sp, *prefill_tile(Sp, ex.bk))
+
+    eng.run([ServeRequest(0, "a", 0.0, 20, 2, 10.0),
+             ServeRequest(1, "a", 0.0, 40, 2, 10.0)])
+    before = eng.jit_stats.prefill_bodies
+    assert before == {entry(32): 1, entry(64): 1}
+    trace = [ServeRequest(i, "a", 0.0, n, 2, 10.0)
+             for i, n in enumerate((5, 40, 100, 100))]
+    rep = eng.run(trace)
+    delta = rep.jit.prefill_bodies - before
+    assert delta == {entry(64): 1, entry(128): 2}
+    assert sum(delta.values()) == sum(
+        r.prompt_len >= eng.prefill_declare_min for r in trace)
 
 
 # ---------------------------------------------------------------------------

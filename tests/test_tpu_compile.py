@@ -21,7 +21,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.dispatch import _dispatch_grouped, _dispatch_shared
-from repro.core.jit import _scan_gemm
+from repro.core.jit import _scan_gemm, prefill_tile
 from repro.kernels import coalesced_gemv, flash_attention
 
 # Yi-9B (configs/yi_9b.py): d_model, d_ff and d_ff's envelope bucket
@@ -100,6 +100,30 @@ def test_stacked_scan_gemm_ffn(one_chip):
     ws = {"up": _spec(one_chip, (8, D, FF_PAD)),
           "down": _spec(one_chip, (8, FF_PAD, D))}
     compiled = jax.jit(scan).lower(_spec(one_chip, (4, D)), ws).compile()
+    _assert_compiled_for_chip(compiled)
+
+
+def test_stacked_scan_gemm_prefill_tile(one_chip):
+    """``_scan_gemm`` over 4 layers of a 2048-row prompt at the prefill
+    body's tile (``prefill_tile``): the query, FFN up and down
+    projections."""
+    bm, bn, bk = prefill_tile(2048, 512)
+
+    def gemm(a, w, n):
+        return _scan_gemm(a, w, n, bm=bm, bn=bn, bk=bk, interpret=False)
+
+    def body(x, w):
+        h = gemm(x, w["wq"], D)
+        up = gemm(h, w["up"], FF)
+        return gemm(up, w["down"], D), None
+
+    def scan(x, w):
+        return jax.lax.scan(body, x, w)[0]
+
+    ws = {"wq": _spec(one_chip, (4, D, D)),
+          "up": _spec(one_chip, (4, D, FF_PAD)),
+          "down": _spec(one_chip, (4, FF_PAD, D))}
+    compiled = jax.jit(scan).lower(_spec(one_chip, (2048, D)), ws).compile()
     _assert_compiled_for_chip(compiled)
 
 
