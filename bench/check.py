@@ -2,8 +2,9 @@
 
 Once the window has closed and the program's state is freed, a sample of
 the requests it finished, drawn from the seed with each tenant's longest
-request in it, is run through the plain float32 reference (``reference/``)
-over its prompt and its served tokens. The number compared, per model, is
+request in it, is run through its model family's plain float32 reference
+(``reference/``, called by ``families/<family>.py``) over its prompt and its
+served tokens. The number compared, per model, is
 the widest gap by which a served (greedy) token's reference logit lies
 below the reference's best logit at that position, and the mean of that
 shortfall over the sampled positions:
@@ -28,7 +29,7 @@ from typing import Callable, Dict, List, Optional, Sequence
 import jax.numpy as jnp
 import numpy as np
 
-from reference import llama, mamba2
+import family
 from traffic import rng_for
 
 TOKENS_PER_TENANT = 256      # served tokens sampled per tenant, at least
@@ -85,7 +86,7 @@ def sample(served: Sequence[Served], n_tenants: int, seed: int
     return out
 
 
-def _inputs(s: Served, seq: int, reads: int):
+def reference_inputs(s: Served, seq: int, reads: int):
     """The reference's input (prompt + served tokens but the last, padded
     to ``seq``) and the positions whose logits chose each served token."""
     toks = np.zeros(seq, np.int32)
@@ -99,28 +100,11 @@ def _inputs(s: Served, seq: int, reads: int):
 
 def reference_logits(spec: dict, params, reqs: Sequence[Served], seq: int,
                      reads: int, wfn=None) -> List[np.ndarray]:
-    """Reference logits [len(tokens), vocab] of each request, in blocks
-    that fit the chip: one request at a time for attention models, a
-    fixed batch of MAX_PER_TENANT for the recurrent one."""
-    kw = {} if wfn is None else {"wfn": wfn}
-    out = []
-    if spec["family"] == "llama":
-        for s in reqs:
-            toks, read = _inputs(s, seq, reads)
-            lg = llama.logits(params, spec, toks, read, **kw)
-            out.append(np.asarray(lg[: len(s.tokens)]))
-        return out
-    if spec["family"] == "mamba2":
-        for i in range(0, len(reqs), MAX_PER_TENANT):
-            block = list(reqs[i:i + MAX_PER_TENANT])
-            toks = np.zeros((MAX_PER_TENANT, seq), np.int32)
-            read = np.zeros((MAX_PER_TENANT, reads), np.int32)
-            for j, s in enumerate(block):
-                toks[j], read[j] = _inputs(s, seq, reads)
-            lg = np.asarray(mamba2.logits(params, spec, toks, read, **kw))
-            out += [lg[j, : len(s.tokens)] for j, s in enumerate(block)]
-        return out
-    raise ValueError(f"no reference for family {spec['family']!r}")
+    """Reference logits [len(tokens), vocab] of each request, from the
+    model family's reference, in blocks of the family's choosing that fit
+    the chip."""
+    return family.of(spec).reference_logits(spec, params, reqs, seq, reads,
+                                            wfn=wfn)
 
 
 def gaps(ref: np.ndarray, chosen: Sequence[int]) -> np.ndarray:

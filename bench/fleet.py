@@ -12,12 +12,13 @@ from __future__ import annotations
 import json
 import math
 from pathlib import Path
-from typing import Any, Dict, List, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+import family
 from traffic import rng_for
 
 CONFIG_DIR = Path(__file__).resolve().parent / "configs"
@@ -35,38 +36,17 @@ def padded_vocab(spec: dict) -> int:
 
 
 def model_config(name: str, spec: dict):
-    """The program's ``ModelConfig`` for one model entry of a fleet file."""
-    from repro.configs.base import ModelConfig, SSMConfig
-    if spec["family"] == "llama":
-        return ModelConfig(
-            name=name, arch_type="dense",
-            num_layers=spec["num_hidden_layers"], d_model=spec["hidden_size"],
-            num_heads=spec["num_attention_heads"],
-            num_kv_heads=spec["num_key_value_heads"],
-            head_dim=spec["head_dim"], d_ff=spec["intermediate_size"],
-            vocab_size=spec["vocab_size"], rope_theta=spec["rope_theta"],
-            norm_eps=spec["rms_norm_eps"],
-            tie_embeddings=spec["tie_word_embeddings"])
-    if spec["family"] == "mamba2":
-        s = spec["ssm_cfg"]
-        if s["ngroups"] != 1:
-            raise ValueError("the program's SSD block has one B/C group")
-        return ModelConfig(
-            name=name, arch_type="ssm", num_layers=spec["n_layer"],
-            d_model=spec["d_model"], num_heads=0, num_kv_heads=0, d_ff=0,
-            vocab_size=padded_vocab(spec), norm_eps=spec["rms_norm_eps"],
-            tie_embeddings=spec["tie_embeddings"],
-            ssm=SSMConfig(d_state=s["d_state"], head_dim=s["headdim"],
-                          expand=s["expand"], chunk_size=s["chunk_size"],
-                          d_conv=s["d_conv"]))
-    raise ValueError(f"unknown model family {spec['family']!r}")
+    """The program's ``ModelConfig`` for one model entry of a fleet file,
+    from the entry's family module."""
+    return family.of(spec).model_config(name, spec)
 
 
 def _path(keypath) -> Tuple[str, ...]:
     return tuple(str(getattr(k, "key", k)) for k in keypath)
 
 
-def _leaf(path: Tuple[str, ...], key, shape, dtype, tied: bool):
+def _leaf(path: Tuple[str, ...], key, shape, dtype, tied: bool,
+          extra: Optional[Callable] = None):
     """One weight leaf. Matrices: normal with std 1/sqrt(fan_in); norm
     gains: stored as the deviation from 1 (the program's convention), drawn
     N(0, 0.1); the Mamba2 scalars follow the published initialisation (A in
@@ -79,7 +59,10 @@ def _leaf(path: Tuple[str, ...], key, shape, dtype, tied: bool):
     whatever the layers compute. Tied tables are drawn at 0.02/sqrt(d)
     (the input row's lead falls to a quarter of the spread), so that the
     token chosen depends on the layers, which is what the comparison with
-    the reference has to see."""
+    the reference has to see.
+
+    A leaf whose name no rule here knows is drawn by ``extra``, the
+    family's ``leaf`` rule, where it gives one (None: it has no rule)."""
     name = path[-1]
     f32 = jnp.float32
 
@@ -107,14 +90,19 @@ def _leaf(path: Tuple[str, ...], key, shape, dtype, tied: bool):
                        ).astype(dtype)
     if name == "D":
         return (1.0 + 0.1 * jax.random.normal(key, shape, f32)).astype(dtype)
+    drawn = extra(path, key, shape, dtype, tied) if extra else None
+    if drawn is not None:
+        return drawn
     raise KeyError(f"no rule for weight leaf {'/'.join(path)}")
 
 
-def make_weights(model, seed: int, stream: int, device=None):
+def make_weights(model, seed: int, stream: int, device=None,
+                 leaf: Optional[Callable] = None):
     """The model's param tree, drawn from (seed, stream) in one jitted call
     on ``device`` in the dtypes the program serves, by the counter-based
     ``rbg`` generator (the default threefry takes tens of seconds for a
-    Yi-9B tree on a v5e)."""
+    Yi-9B tree on a v5e). ``leaf`` is the family's rule for leaves
+    ``_leaf`` does not know."""
     abstract = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     tied = "unembed" not in abstract
     leaves, treedef = jax.tree_util.tree_flatten_with_path(abstract)
@@ -126,7 +114,7 @@ def make_weights(model, seed: int, stream: int, device=None):
         keys = jax.random.split(jax.random.key(seed, impl="rbg"),
                                 len(leaves))
         return jax.tree_util.tree_unflatten(treedef, [
-            _leaf(_path(p), k, a.shape, a.dtype, tied)
+            _leaf(_path(p), k, a.shape, a.dtype, tied, leaf)
             for (p, a), k in zip(leaves, keys)])
 
     fn = jax.jit(draw, device=device) if device is not None \
@@ -162,8 +150,9 @@ class Fleet:
         self.tree_of: List[int] = [trees.index(k) for k in keys]
         models = [Model(self.model_cfgs[m], param_dtype=dtype)
                   for m, _ in trees]
-        drawn = [make_weights(mo, seed, w)
-                 for mo, (_, w) in zip(models, trees)]
+        drawn = [make_weights(mo, seed, w, leaf=getattr(
+                     family.of(self.cfg["models"][m]), "leaf", None))
+                 for mo, (m, w) in zip(models, trees)]
         self.models = [models[i] for i in self.tree_of]
         self.params: List[Any] = [drawn[i] for i in self.tree_of]
 

@@ -9,7 +9,9 @@ p95, the median request time (due to last token), the drain after the
 window closed, and the requests completed per second over the window and
 its drain. Far above the knee that completed rate is the highest rate the
 system sustains; a cell is run at about four fifths of it, written as a
-number into its traffic file. The benchmark's own runs never run this.
+number into its traffic file, or into its configuration file where the
+mix does not name the configuration (``traffic.offered``). The
+benchmark's own runs never run this.
 """
 from __future__ import annotations
 
@@ -41,7 +43,9 @@ def main(argv=None) -> int:
     for rate in args.rates:
         def at_rate(name, rate=rate):
             mix = load(name)
-            mix["rate_per_s"] = {cell["config"]: rate}
+            _, shares = traffic.offered(mix, cell["config"])
+            mix.update(rate_per_s={cell["config"]: rate},
+                       tenant_shares=shares)
             return mix
         traffic.load_mix = at_rate
         s = run.Session(jax, cell, args.seed, args.seconds)

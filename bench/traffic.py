@@ -18,12 +18,18 @@ A mix file holds:
 * ``rate_per_s``: the offered rate for each configuration that runs the
   mix, by configuration name (set once from a sweep on the chip);
 * ``tenant_shares``: the popularity of the fleet's tenants, in the
-  configuration's tenant order;
+  configuration's tenant order, for the configurations ``rate_per_s``
+  names;
 * ``prompt_len`` and ``output_len``: lognormal ``median`` and ``sigma``,
   clipped to ``min``..``max``, in whole tokens;
 * ``slo_s``: the deadline each request carries into the scheduler;
 * ``schedule_seed``: the seed of the schedule's order;
 * ``warmup``: the ``output_len`` of the warm-up requests.
+
+A configuration that the mix's ``rate_per_s`` does not name gives its rate
+and shares in its own file, ``bench/configs/<config>.json``, under
+``"traffic": {"<mix>": {"rate_per_s": r, "tenant_shares": [...]}}``: a new
+configuration joins a mix without an edit to the mix's file.
 """
 from __future__ import annotations
 
@@ -49,7 +55,24 @@ class Request:
 
 
 def load_mix(name: str) -> dict:
-    return json.loads((TRAFFIC_DIR / f"{name}.json").read_text())
+    """The mix's parameters, with its ``name``."""
+    return dict(json.loads((TRAFFIC_DIR / f"{name}.json").read_text()),
+                name=name)
+
+
+def offered(mix: dict, config_name: str):
+    """(rate_per_s, tenant_shares) of the mix for one configuration: the
+    mix's own where its ``rate_per_s`` names the configuration, else those
+    the configuration file gives for the mix."""
+    if config_name in mix.get("rate_per_s", {}):
+        return mix["rate_per_s"][config_name], mix["tenant_shares"]
+    from fleet import load_config
+    own = load_config(config_name).get("traffic", {}).get(mix["name"])
+    if own is None:
+        raise ValueError(f"no rate for {config_name!r} in mix "
+                         f"{mix['name']!r} nor under \"traffic\" in its "
+                         f"configuration file")
+    return own["rate_per_s"], own["tenant_shares"]
 
 
 def rng_for(seed: int, *stream: int) -> np.random.Generator:
@@ -88,7 +111,7 @@ def _split(shares: Sequence[float], n: int) -> List[int]:
 def schedule(mix: dict, config_name: str, seconds: float) -> List[Request]:
     """The window's requests, due in [0, seconds): the same for every run
     of the mix."""
-    rate = mix["rate_per_s"][config_name]
+    rate, shares = offered(mix, config_name)
     n = max(2, int(round(rate * seconds)))
     rng = rng_for(mix["schedule_seed"], 1)
     gaps = rng.permutation(gap_set(mix["arrivals"], rate, n))
@@ -96,7 +119,7 @@ def schedule(mix: dict, config_name: str, seconds: float) -> List[Request]:
     due *= seconds * (n - 1) / n / due[-1]
     plens = rng.permutation(lognormal_set(mix["prompt_len"], n))
     olens = rng.permutation(lognormal_set(mix["output_len"], n))
-    tenants = rng.permutation(_split(mix["tenant_shares"], n))
+    tenants = rng.permutation(_split(shares, n))
     return [Request(i, int(tenants[i]), float(due[i]), int(plens[i]),
                     int(olens[i]), float(mix["slo_s"])) for i in range(n)]
 
