@@ -1,4 +1,5 @@
-"""Config registry: 10 assigned architectures + reduced smoke variants.
+"""Config registry: 10 assigned architectures, Falcon-H1-34B (the hybrid
+benchmark configuration) and reduced smoke variants.
 
 ``get_config(arch_id)`` returns the exact assigned config; ``smoke_config``
 returns a reduced variant of the same family (≤2 layers, d_model ≤ 512,
@@ -21,6 +22,7 @@ from repro.configs.whisper_tiny import CONFIG as _whisper_tiny
 from repro.configs.hymba_1_5b import CONFIG as _hymba_1_5b
 from repro.configs.llama4_maverick_400b_a17b import CONFIG as _llama4
 from repro.configs.gemma3_1b import CONFIG as _gemma3_1b
+from repro.configs.falcon_h1_34b import CONFIG as _falcon_h1_34b
 
 REGISTRY: Dict[str, ModelConfig] = {
     c.name: c
@@ -35,6 +37,7 @@ REGISTRY: Dict[str, ModelConfig] = {
         _hymba_1_5b,
         _llama4,
         _gemma3_1b,
+        _falcon_h1_34b,
     ]
 }
 
@@ -52,6 +55,7 @@ SKIPPED_PAIRS = frozenset(
         "internvl2-2b",
         "grok-1-314b",
         "whisper-tiny",
+        "falcon-h1-34b",
     )
 )
 

@@ -30,16 +30,66 @@ class MoEConfig:
 
 @dataclasses.dataclass(frozen=True)
 class SSMConfig:
-    """Mamba-2 (SSD — state space duality, arXiv:2405.21060) settings."""
+    """Mamba-2 (SSD — state space duality, arXiv:2405.21060) settings.
+
+    ``d_inner`` is the mixer's inner width; 0 means ``expand × d_model``
+    (Falcon-H1 gives it apart, as ``mamba_d_ssm``). B and C come in
+    ``n_groups`` groups, the heads split evenly across them, and the gated
+    RMSNorm normalizes each group on its own. ``in_multiplier`` scales the
+    mixer's input and ``proj_multipliers`` the five parts [z | x | B | C |
+    dt] of the in-projection's output (Falcon-H1's ``ssm_in_multiplier``
+    and ``ssm_multipliers``); both default to no change.
+    """
 
     d_state: int = 128
     head_dim: int = 64
     expand: int = 2
     chunk_size: int = 64
     d_conv: int = 4  # depthwise conv width in the mamba block
+    n_groups: int = 1
+    d_inner: int = 0
+    in_multiplier: float = 1.0
+    proj_multipliers: Tuple[float, ...] = (1.0, 1.0, 1.0, 1.0, 1.0)
+
+    def inner(self, d_model: int) -> int:
+        return self.d_inner or self.expand * d_model
 
     def num_heads(self, d_model: int) -> int:
-        return (self.expand * d_model) // self.head_dim
+        return self.inner(d_model) // self.head_dim
+
+    def conv_dim(self, d_model: int) -> int:
+        """Channels of the depthwise conv: x, B and C."""
+        return self.inner(d_model) + 2 * self.n_groups * self.d_state
+
+    def in_proj_dim(self, d_model: int) -> int:
+        """Outputs of the in-projection: z, x, B, C and dt."""
+        return self.inner(d_model) + self.conv_dim(d_model) \
+            + self.num_heads(d_model)
+
+
+@dataclasses.dataclass(frozen=True)
+class Multipliers:
+    """Constant scales of the residual stream's paths (Falcon-H1's µP
+    multipliers). Each defaults to no change: ``embedding`` None is the
+    repository's sqrt(d_model) scale of the token embedding, and the
+    others are 1. The hybrid block adds ``attention_out`` × attention and
+    ``ssm_out`` × SSM to the residual; attention reads its input scaled by
+    ``attention_in`` and scales its keys by ``key``; the SwiGLU FFN scales
+    its gate projection by ``mlp_gate`` and its output by ``mlp_down``.
+    The SSM mixer's own multipliers live in ``SSMConfig``."""
+
+    embedding: Optional[float] = None
+    lm_head: float = 1.0
+    key: float = 1.0
+    attention_in: float = 1.0
+    attention_out: float = 1.0
+    ssm_out: float = 1.0
+    mlp_gate: float = 1.0
+    mlp_down: float = 1.0
+
+
+# a hybrid that names no multipliers averages its two mixers (Hymba's rule)
+HYBRID_MEAN = Multipliers(attention_out=0.5, ssm_out=0.5)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -73,6 +123,9 @@ class ModelConfig:
     # --- optional sub-configs ------------------------------------------------
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
+    # None: no change, except that a hybrid averages its mixers
+    # (``HYBRID_MEAN``); see ``mult``
+    multipliers: Optional[Multipliers] = None
     # --- audio/vlm frontend stubs -------------------------------------------
     num_encoder_layers: int = 0           # audio (whisper) encoder depth
     encoder_seq_len: int = 0              # frames (audio) per the model card
@@ -91,6 +144,14 @@ class ModelConfig:
         if self.num_heads == 0:  # attention-free
             return 0
         return self.d_model // self.num_heads
+
+    @property
+    def mult(self) -> Multipliers:
+        """The multipliers in force: the config's own, else no change (a
+        hybrid's two mixers averaged)."""
+        if self.multipliers is not None:
+            return self.multipliers
+        return HYBRID_MEAN if self.arch_type == "hybrid" else Multipliers()
 
     @property
     def padded_vocab(self) -> int:
@@ -128,19 +189,13 @@ class ModelConfig:
 
     # ------------------------------------------------------------------
     def param_count(self) -> int:
-        """Analytical parameter count (embeddings included once if tied)."""
+        """Analytical parameter count (embeddings included once if tied),
+        from the config's own fields: for the dense, MoE, SSM and hybrid
+        stacks it equals the size of ``Model.init``'s tree."""
         d, dff, L = self.d_model, self.d_ff, self.num_layers
         hd = self.resolved_head_dim
         if self.arch_type == "ssm":
-            s = self.ssm or SSMConfig()
-            d_inner = s.expand * d
-            nheads = s.num_heads(d)
-            # in_proj: d -> (2*d_inner + 2*n_groups*d_state + nheads); use
-            # n_groups = 1 for simplicity.
-            in_proj = d * (2 * d_inner + 2 * s.d_state + nheads)
-            out_proj = d_inner * d
-            conv = s.d_conv * (d_inner + 2 * s.d_state)
-            per_layer = in_proj + out_proj + conv + 2 * d
+            per_layer = self._mamba_params() + 2 * d
             body = L * per_layer
         else:
             q = d * (self.num_heads * hd)
@@ -153,10 +208,7 @@ class ModelConfig:
                 ffn = 3 * d * dff  # gate/up/down (SwiGLU)
             per_layer = attn + ffn + 2 * d
             if self.arch_type == "hybrid":
-                s = self.ssm or SSMConfig(d_state=16)
-                d_inner = s.expand * d
-                nheads = s.num_heads(d)
-                per_layer += d * (2 * d_inner + 2 * s.d_state + nheads) + d_inner * d
+                per_layer += self._mamba_params()
             body = L * per_layer
             if self.is_encdec:
                 enc_per_layer = attn + 3 * d * dff + 2 * d
@@ -165,7 +217,15 @@ class ModelConfig:
         emb = self.padded_vocab * d
         if not self.tie_embeddings:
             emb *= 2
-        return body + emb
+        return body + emb + d      # + the final norm's gain
+
+    def _mamba_params(self) -> int:
+        """One Mamba-2 mixer: in/out projections, the conv's weights and
+        biases, dt_bias, A_log, D and the gated norm's gain."""
+        s, d = self.ssm or SSMConfig(), self.d_model
+        di, H = s.inner(d), s.num_heads(d)
+        return d * s.in_proj_dim(d) + di * d \
+            + (s.d_conv + 1) * s.conv_dim(d) + 3 * H + di
 
     def active_param_count(self) -> int:
         """Params touched per token (MoE: top_k of num_experts FFNs)."""

@@ -216,6 +216,33 @@ def device_weight_budget(n_devices: int = 1) -> int:
     return total + (1 << 30) * max(0, n_devices - len(jax.devices()))
 
 
+# the alignment of a layer-stacked operand's envelope: the decode and
+# prefill tiles (bn, bk <= 512) divide it
+STACK_ALIGN = 512
+
+
+def stacked_envelope(x: int) -> int:
+    """One padded extent of a layer-stacked weight operand: the next
+    multiple of ``STACK_ALIGN``, or the power-of-two ``envelope_bucket`` up
+    to it. A stacked operand is dispatched alone, in its own scan, so it
+    needs no power-of-two bucket: aligned envelopes leave published widths
+    such as 4096, 5120 or 21504 unpadded (served without a copy) and pad
+    11008 to 11264, where a power of two copies a matrix at up to 1.6 times
+    its size and computes the padding."""
+    if x <= STACK_ALIGN:
+        return envelope_bucket(x)
+    return -(-x // STACK_ALIGN) * STACK_ALIGN
+
+
+def pad_stacked(w: jax.Array, K: int, N: int) -> jax.Array:
+    """``w`` [..., k, n] zero-padded to [..., K, N]; ``w`` itself where it
+    already has that shape."""
+    k, n = int(w.shape[-2]), int(w.shape[-1])
+    if (k, n) == (K, N):
+        return w
+    return jnp.pad(w, [(0, 0)] * (w.ndim - 2) + [(0, K - k), (0, N - n)])
+
+
 def _pow2(n: int) -> int:
     """Smallest power of two ≥ n (n ≥ 1)."""
     return 1 << max(n - 1, 0).bit_length()
@@ -316,8 +343,9 @@ class SuperkernelExecutor:
     def stacked_operand(self, wkey: Tuple, k: int, n: int, layers: int,
                         weight_fn, guard: Sequence[jax.Array], *,
                         group=None, device: int = 0) -> jax.Array:
-        """One LAYER-STACKED weight operand — [L, ..., K, N] padded to the
-        bucketed (K, N) envelope — from the persistent cache.
+        """One LAYER-STACKED weight operand — [L, ..., K, N] padded to its
+        aligned (K, N) envelope (``stacked_envelope``) — from the
+        persistent cache.
 
         This is the stacked-template analogue of ``_packed_weights``: one
         cache entry per stacked operand per params generation (entry count
@@ -332,19 +360,17 @@ class SuperkernelExecutor:
         A real hot-swap replaces the params tree → new ``id(params)`` in
         ``wkey`` → new cache key; ``group`` (params-free slot identity)
         eagerly drops the superseded entry, exactly like
-        ``_packed_weights``."""
-        K = envelope_bucket(int(k))
-        N = envelope_bucket(int(n))
+        ``_packed_weights``. An operand that already fills its envelope is
+        served as it is, with no copy."""
+        K = stacked_envelope(int(k))
+        N = stacked_envelope(int(n))
         # device id keyed for the same reason as _packed_weights: the
         # shared cache holds one resident stack PER DEVICE
         key = ("wstack", device, wkey, int(layers), K, N,
                str(guard[0].dtype) if guard else "")
 
         def build() -> jax.Array:
-            w = weight_fn()
-            pad = [(0, 0)] * (w.ndim - 2) + [(0, K - int(w.shape[-2])),
-                                             (0, N - int(w.shape[-1]))]
-            return jnp.pad(w, pad)
+            return pad_stacked(weight_fn(), K, N)
 
         inval0 = self.weight_cache.stats.invalidations
         value, hit = self.weight_cache.get_or_build_flagged(

@@ -85,8 +85,10 @@ and plan/weight-cache entries become O(1) in depth. Design points:
     — the same [L, ...] layout the per-layer path's ``jnp.stack`` built;
   * the scan body's GEMMs (``_scan_gemm``) replicate the dispatch
     executor's solo-dispatch bucketing EXACTLY at the tile they are given
-    (same m-tile bucket, same power-of-two envelopes, same bk), which is
-    what makes the stacked path bit-identical to per-layer emission
+    (same m-tile bucket, same bk; the stacked operand's envelope is only
+    aligned to 512, ``dispatch.stacked_envelope``, which drops zero K
+    blocks and padding columns), which is what makes the stacked path
+    bit-identical to per-layer emission
     (tests/test_stacked_templates.py asserts logits AND cache identity
     for dense decode/prefill, MoE and SSM);
   * cost/coalescing granularity: a stacked op is charged as L sequential
@@ -124,7 +126,7 @@ from repro.core.costmodel import (BlockConfig, CostModel, GemmShape,
                                   attached_device)
 from repro.core.dispatch import (DispatchStats, SuperkernelExecutor,
                                  _tile_bucket, device_weight_budget,
-                                 envelope_bucket)
+                                 pad_stacked, stacked_envelope)
 from repro.core.kernelspec import make_op, op_aspect
 from repro.core.plancache import PlanCache, PlanCacheStats
 from repro.core.scheduler import OoOScheduler, SchedulerConfig
@@ -133,7 +135,8 @@ from repro.core.schedtrace import (DispatchRecord, OpRecord, ProgramAdmit,
 from repro.core.spans import span, spanned
 from repro.kernels.coalesced_gemm import coalesced_gemm
 from repro.kernels.ops import check_vmem
-from repro.models.layers import rmsnorm, apply_rope
+from repro.configs.base import Multipliers
+from repro.models.layers import apply_rope, rmsnorm, scale, scale_embedding
 
 
 # ---------------------------------------------------------------------------
@@ -251,18 +254,21 @@ def _scan_gemm(a: jax.Array, w_pad: jax.Array, n_real: int, *, bm: int,
                bn: int, bk: int, interpret: bool) -> jax.Array:
     """One GEMM inside a scanned layer body, replicating the dispatch
     executor's solo dispatch EXACTLY at the tile it is given — same m-tile
-    bucket, same padded (K, N) envelope (``w_pad`` is one xs slice of a
-    cached ``stacked_operand``), same block clamping — so a stacked body
-    is bit-identical to the per-layer path dispatching each stage at that
-    tile. Every output element sums its K blocks in bk order, so tiles
-    that share bk give the same bits."""
+    bucket, same block clamping, with ``w_pad`` one xs slice of a cached
+    ``stacked_operand`` — so a stacked body is bit-identical to the
+    per-layer path dispatching each stage at that tile. Every output
+    element sums its K blocks in bk order, so tiles that share bk give the
+    same bits, and the stacked operand's smaller aligned envelope drops
+    only zero blocks of K and padding columns of N."""
     m = int(a.shape[0])
     K, N = int(w_pad.shape[-2]), int(w_pad.shape[-1])
     m_tiles = _tile_bucket([m], bm)
     ap = jnp.pad(a, ((0, m_tiles * bm - m), (0, K - int(a.shape[1]))))
+    # an aligned envelope (dispatch.stacked_envelope) is a multiple of 512,
+    # not a power of two: a larger live-tuned tile shrinks to a divisor
     out = coalesced_gemm(ap, w_pad[None], jnp.zeros((m_tiles,), jnp.int32),
-                         bm=bm, bn=min(bn, N), bk=min(bk, K),
-                         interpret=interpret)
+                         bm=bm, bn=math.gcd(min(bn, N), N),
+                         bk=math.gcd(min(bk, K), K), interpret=interpret)
     return out[:m, :n_real]
 
 
@@ -608,7 +614,7 @@ def _emit_decode_embed(cfg: ModelConfig, params, stages: List[Stage]) -> None:
 
     def embed(env):
         x = params["embed"][env["tokens"]]
-        env["x"] = (x * jnp.asarray(jnp.sqrt(cfg.d_model), x.dtype))[:, 0]
+        env["x"] = scale_embedding(x, cfg.d_model, cfg.mult.embedding)[:, 0]
         env["pos"] = env["cache"]["pos"]
 
     stages.append(GlueStage(embed, reads=("tokens", "cache"),
@@ -642,7 +648,8 @@ def _emit_unembed(cfg: ModelConfig, params, stages: List[Stage], *,
     stages.append(GemmStage(
         "unembed", weight_key(cfg.name, pid, "unembed"), wfn,
         lambda env: env["hf"],
-        lambda env, out: env.__setitem__("logits", out),
+        lambda env, out: env.__setitem__("logits",
+                                         scale(out, cfg.mult.lm_head)),
         shape=GemmShape(m=m_rows, n=n, k=cfg.d_model),
         reads=("hf",), writes=("logits",)))
 
@@ -1015,6 +1022,14 @@ def _build_gqa_decode_template(model, params, batch: int, *,
     return ProgramTemplate(stages=stages, batch=B, model_name=cfg.name)
 
 
+def _plain_blocks(cfg: ModelConfig) -> None:
+    """The GQA + FFN templates serve the embedding and head multipliers
+    only: a config whose blocks scale their own paths (keys, attention's
+    input and output, the FFN) runs the hybrid template."""
+    assert dataclasses.replace(cfg.mult, embedding=None, lm_head=1.0) \
+        == Multipliers(), f"{cfg.name}: block multipliers unsupported here"
+
+
 def build_dense_decode_template(model, params, batch: int, *,
                                 stacked: bool = True) -> ProgramTemplate:
     """Compile the decode step of a dense GQA model into a ProgramTemplate.
@@ -1029,6 +1044,7 @@ def build_dense_decode_template(model, params, batch: int, *,
     per-layer emission (the bit-identity oracle).
     """
     assert model.cfg.arch_type in ("dense", "vlm"), model.cfg.arch_type
+    _plain_blocks(model.cfg)
     if stacked:
         return _build_stacked_gqa_decode_template(model, params, batch)
     return _build_gqa_decode_template(model, params, batch)
@@ -1085,6 +1101,7 @@ def build_moe_decode_template(model, params, batch: int, *,
     """
     cfg: ModelConfig = model.cfg
     assert cfg.arch_type == "moe" and cfg.has_moe, cfg.arch_type
+    _plain_blocks(cfg)
     if stacked:
         return _build_stacked_gqa_decode_template(model, params, batch,
                                                   moe=True)
@@ -1182,8 +1199,8 @@ def _build_stacked_ssm_decode_template(model, params, batch: int
     from repro.models import ssm as ssm_lib
     scfg = cfg.ssm
     B, d = batch, cfg.d_model
-    d_inner = scfg.expand * d
-    n_in = 2 * d_inner + 2 * scfg.d_state + scfg.num_heads(d)
+    d_inner = scfg.inner(d)
+    n_in = scfg.in_proj_dim(d)
     eps = cfg.norm_eps
     blocks = params["blocks"]
     mamba = blocks["mamba"]
@@ -1299,8 +1316,8 @@ def build_ssm_decode_template(model, params, batch: int, *,
     from repro.models import ssm as ssm_lib
     scfg = cfg.ssm
     B, d = batch, cfg.d_model
-    d_inner = scfg.expand * d
-    n_in = 2 * d_inner + 2 * scfg.d_state + scfg.num_heads(d)
+    d_inner = scfg.inner(d)
+    n_in = scfg.in_proj_dim(d)
     blocks = params["blocks"]
     pid = id(params)
     stages: List[Stage] = []
@@ -1362,6 +1379,204 @@ def build_ssm_decode_template(model, params, batch: int, *,
 
     glue(finish, reads=("cache", "new_layers"), writes=("cache",))
     return ProgramTemplate(stages=stages, batch=B, model_name=cfg.name)
+
+
+# ---------------------------------------------------------------------------
+# hybrid decode programs: attention and SSM heads side by side in each layer
+# ---------------------------------------------------------------------------
+
+def hybrid_program_cache_key(model, params, batch: int, cache) -> Tuple:
+    """Plan-cache key for a hybrid decode template: (model identity, batch,
+    dtype, the KV rows' and the recurrent state's geometry). Guard
+    discipline as for dense. The template is stacked only."""
+    lay = cache["layers"]
+    return ("hybrid-decode", model.cfg.name, id(model), batch,
+            str(params["embed"].dtype), str(lay["k"].dtype),
+            tuple(lay["k"].shape), str(lay["conv"].dtype),
+            tuple(lay["conv"].shape), tuple(lay["h"].shape),
+            ("stacked", True, model.cfg.num_layers))
+
+
+def _stacked_hybrid_body_stage(model, params, B: int, lo: int, hi: int
+                               ) -> StackedGemmStage:
+    """ONE scanned decode body covering layers [lo, hi) of a hybrid model.
+    Per layer, on the ln1 output: the GQA stages of the dense body (wq/wk/
+    wv, ``_gqa_decode_attend``, wo) and the SSM stages of the SSM body (in
+    projection, ``ssm_lib.decode_core``, out projection) side by side; the
+    two outputs join the residual by ``combine_mixers``, the model's one
+    combine rule; then the SwiGLU FFN. The µP multipliers apply where
+    ``models/transformer.py`` applies them."""
+    from repro.models import ssm as ssm_lib
+    from repro.models.transformer import combine_mixers
+    cfg: ModelConfig = model.cfg
+    scfg, mu = cfg.ssm, cfg.mult
+    hd, d = cfg.resolved_head_dim, cfg.d_model
+    n_in = scfg.in_proj_dim(d)
+    eps = cfg.norm_eps
+    blocks = params["blocks"]
+    pid = id(params)
+    Lsub = hi - lo
+    is_global = bool(cfg.layer_is_global(lo))
+
+    def sop(tag, name, arr, n, k):
+        return StackedOperand(
+            tag, weight_key(cfg.name, pid, name, stack=(lo, hi)),
+            GemmShape(m=B, n=n, k=k, layers=Lsub),
+            lambda a=arr: _stack_slice(a, lo, hi), (arr,))
+
+    attn, mamba, mlp = blocks["attn"], blocks["mamba"], blocks["mlp"]
+    operands = [
+        sop("attn_wq", "wq", attn["wq"], cfg.num_heads * hd, d),
+        sop("attn_wk", "wk", attn["wk"], cfg.num_kv_heads * hd, d),
+        sop("attn_wv", "wv", attn["wv"], cfg.num_kv_heads * hd, d),
+        sop("attn_wo", "wo", attn["wo"], d, cfg.num_heads * hd),
+        sop("ssm_in_proj", "in_proj", mamba["in_proj"], n_in, d),
+        sop("ssm_out_proj", "out_proj", mamba["out_proj"], d,
+            scfg.inner(d)),
+        sop("ffn_gate", "w_gate", mlp["w_gate"], cfg.d_ff, d),
+        sop("ffn_up", "w_up", mlp["w_up"], cfg.d_ff, d),
+        sop("ffn_down", "w_down", mlp["w_down"], d, cfg.d_ff),
+    ]
+    # decode_core reads only the conv/dt/A/D/norm leaves; the projections
+    # are the declared stacked operands above
+    aux = {"ln1": _stack_slice(blocks["ln1"], lo, hi),
+           "ln2": _stack_slice(blocks["ln2"], lo, hi),
+           "mamba": {k: _stack_slice(v, lo, hi) for k, v in mamba.items()
+                     if k not in ("in_proj", "out_proj")}}
+    jits: Dict[Tuple, Callable] = {}
+
+    def make_scan(bm: int, bn: int, bk: int, interpret: bool):
+        def gemm(a, w, n):
+            return _scan_gemm(a, w, n, bm=bm, bn=bn, bk=bk,
+                              interpret=interpret)
+
+        # per-layer params enter as jit ARGUMENTS (xs), never closures
+        # (see _stacked_dense_body_stage)
+        def hybrid_decode_layers(x, pos_in, kc_full, vc_full, conv_full,
+                                 h_full, w, aux):
+            pos = jnp.broadcast_to(pos_in, (B,))
+
+            def body(carry, per):
+                wl = per["w"]
+                h = rmsnorm(carry, per["ln1"], eps)
+                ha = scale(h, mu.attention_in)
+                q = gemm(ha, wl["attn_wq"], cfg.num_heads * hd)
+                k = scale(gemm(ha, wl["attn_wk"], cfg.num_kv_heads * hd),
+                          mu.key)
+                v = gemm(ha, wl["attn_wv"], cfg.num_kv_heads * hd)
+                attn_out, kc_new, vc_new = _gqa_decode_attend(
+                    cfg, B, q, k, v, per["kc"], per["vc"], pos, is_global,
+                    h.dtype)
+                a = gemm(attn_out, wl["attn_wo"], d)
+                y, st = ssm_lib.decode_core(
+                    per["mamba"], gemm(h, wl["ssm_in_proj"], n_in),
+                    {"conv": per["conv"], "h": per["h"]}, scfg, d)
+                x2 = carry + combine_mixers(
+                    cfg, a, gemm(y, wl["ssm_out_proj"], d))
+                h2 = rmsnorm(x2, per["ln2"], eps)
+                gate = scale(gemm(h2, wl["ffn_gate"], cfg.d_ff), mu.mlp_gate)
+                up = gemm(h2, wl["ffn_up"], cfg.d_ff)
+                down = gemm(jax.nn.silu(gate) * up, wl["ffn_down"], d)
+                return x2 + scale(down, mu.mlp_down), \
+                    (kc_new, vc_new, st["conv"], st["h"])
+
+            xs = dict(aux, kc=kc_full[lo:hi], vc=vc_full[lo:hi],
+                      conv=conv_full[lo:hi], h=h_full[lo:hi], w=w)
+            return jax.lax.scan(body, x, xs)
+
+        return hybrid_decode_layers
+
+    def run(env, padded, ex, block=None):
+        key = (ex.bm, ex.bn, ex.bk, ex.interpret) if block is None else \
+            (block.bm, block.bn, block.bk, ex.interpret)
+        fn = jits.get(key)
+        if fn is None:
+            fn = jits[key] = jax.jit(make_scan(*key))
+        lay = env["cache"]["layers"]
+        x, new = fn(env["x"], jnp.asarray(env["cache"]["pos"]), lay["k"],
+                    lay["v"], lay["conv"], lay["h"], padded, aux)
+        env["x"] = x
+        for name, arr in zip(("k", "v", "conv", "h"), new):
+            env["new_layers"][name].append(arr)
+
+    return StackedGemmStage(
+        tag=f"body_{lo}_{hi}",
+        weight_key=weight_key(cfg.name, pid, "body", stack=(lo, hi)),
+        operands=operands, layers=Lsub, run=run,
+        reads=("x", "cache"), writes=("x", "new_layers"))
+
+
+def _hybrid_head_stage(model, params, B: int) -> StackedGemmStage:
+    """The final norm and the output head as one declared op whose operand
+    is served at the stacked operands' aligned envelope, without a copy:
+    the unembedding GemmStage would pad a 5120 x 261120 head to a
+    power-of-two copy of 4.3 GB."""
+    cfg: ModelConfig = model.cfg
+    d, eps, lm_head = cfg.d_model, cfg.norm_eps, cfg.mult.lm_head
+    pid = id(params)
+    w = _tied_unembed(params) if cfg.tie_embeddings else params["unembed"]
+    V = cfg.padded_vocab
+    operand = StackedOperand(
+        "unembed", weight_key(cfg.name, pid, "unembed", stack=(0, 1)),
+        GemmShape(m=B, n=V, k=d, layers=1), lambda: w, (w,))
+    jits: Dict[Tuple, Callable] = {}
+
+    def make_head(bm: int, bn: int, bk: int, interpret: bool):
+        def hybrid_decode_head(x, w, final_norm):
+            hf = rmsnorm(x, final_norm, eps)
+            return scale(_scan_gemm(hf, w["unembed"], V, bm=bm, bn=bn,
+                                    bk=bk, interpret=interpret), lm_head)
+
+        return hybrid_decode_head
+
+    def run(env, padded, ex, block=None):
+        key = (ex.bm, ex.bn, ex.bk, ex.interpret) if block is None else \
+            (block.bm, block.bn, block.bk, ex.interpret)
+        fn = jits.get(key)
+        if fn is None:
+            fn = jits[key] = jax.jit(make_head(*key))
+        env["logits"] = fn(env["x"], padded, params["final_norm"])
+
+    return StackedGemmStage(
+        tag="head", weight_key=weight_key(cfg.name, pid, "head"),
+        operands=[operand], layers=1, run=run,
+        reads=("x",), writes=("logits",))
+
+
+def build_hybrid_decode_template(model, params, batch: int
+                                 ) -> ProgramTemplate:
+    """Compile the decode step of a hybrid model (Falcon-H1, Hymba) into a
+    ProgramTemplate: embed glue, one scanned body per homogeneous sub-stack
+    (``partition_layers`` over the sliding-window flags, as the dense path
+    does), the head, and an epilogue that writes the KV rows and the
+    recurrent state back into the tenant's cache side by side. Equivalent
+    to ``Model.decode_step`` for arch_type 'hybrid'. There is no per-layer
+    emission: every regime builds this template."""
+    cfg: ModelConfig = model.cfg
+    assert cfg.arch_type == "hybrid" and cfg.has_ssm, cfg.arch_type
+    stages: List[Stage] = []
+    _emit_decode_embed(cfg, params, stages)
+
+    def reset_layers(env):
+        env["new_layers"] = {"k": [], "v": [], "conv": [], "h": []}
+
+    stages.append(GlueStage(reset_layers, reads=(), writes=("new_layers",)))
+    for lo, hi in partition_layers(cfg.global_layer_flags()):
+        stages.append(_stacked_hybrid_body_stage(model, params, batch,
+                                                 lo, hi))
+    stages.append(_hybrid_head_stage(model, params, batch))
+
+    def finish(env):
+        cache = env["cache"]
+        env["cache"] = {
+            "pos": cache["pos"] + 1,
+            "layers": {k: jnp.concatenate(v, axis=0)
+                       for k, v in env["new_layers"].items()},
+        }
+
+    stages.append(GlueStage(finish, reads=("cache", "new_layers"),
+                            writes=("cache",)))
+    return ProgramTemplate(stages=stages, batch=batch, model_name=cfg.name)
 
 
 # ---------------------------------------------------------------------------
@@ -1719,7 +1934,8 @@ class JitStats:
     # to prompt GEMMs (serving acceptance: must be > 0 on long-prompt
     # multi-tenant traces)
     prefill_coalesced: int = 0
-    # non-dense (MoE / SSM) tenant steps compiled+bound as KernelPrograms
+    # non-dense (MoE / SSM / hybrid) tenant steps compiled+bound as
+    # KernelPrograms
     # instead of taking the monolithic batched fallback — the serving
     # engine counts one per decode program it admits for such a tenant
     nondense_programs: int = 0
@@ -2040,12 +2256,9 @@ class JitSession:
                 # operand fresh — same envelope, same bits — but through
                 # neither the persistent cache nor DispatchStats
                 for od in st.operands:
-                    w = od.weight_fn()
-                    K = envelope_bucket(int(od.shape.k))
-                    N = envelope_bucket(int(od.shape.n))
-                    pad = [(0, 0)] * (w.ndim - 2) + \
-                        [(0, K - int(w.shape[-2])), (0, N - int(w.shape[-1]))]
-                    padded[od.tag] = jnp.pad(w, pad)
+                    padded[od.tag] = pad_stacked(
+                        od.weight_fn(), stacked_envelope(int(od.shape.k)),
+                        stacked_envelope(int(od.shape.n)))
             else:
                 h0, m0 = ex.stats.weight_hits, ex.stats.weight_misses
                 for od in st.operands:

@@ -150,9 +150,8 @@ def gemm_population(cfg: ModelConfig, batch: int, mode: str = "decode"
 
     if cfg.arch_type == "ssm":
         s = cfg.ssm
-        d_inner = s.expand * d
-        g("ssm_in_proj", 2 * d_inner + 2 * s.d_state + s.num_heads(d), d)
-        g("ssm_out_proj", d, d_inner)
+        g("ssm_in_proj", s.in_proj_dim(d), d)
+        g("ssm_out_proj", d, s.inner(d))
     else:
         g("attn_q", cfg.num_heads * hd, d)
         g("attn_kv", 2 * cfg.num_kv_heads * hd, d)
@@ -167,9 +166,8 @@ def gemm_population(cfg: ModelConfig, batch: int, mode: str = "decode"
             g("router", cfg.moe.num_experts, d)
         elif cfg.arch_type == "hybrid":
             s = cfg.ssm
-            d_inner = s.expand * d
-            g("ssm_in_proj", 2 * d_inner + 2 * s.d_state + s.num_heads(d), d)
-            g("ssm_out_proj", d, d_inner)
+            g("ssm_in_proj", s.in_proj_dim(d), d)
+            g("ssm_out_proj", d, s.inner(d))
             g("ffn_gate", cfg.d_ff, d)
             g("ffn_up", cfg.d_ff, d)
             g("ffn_down", d, cfg.d_ff)

@@ -19,7 +19,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers import Params, apply_rope, dense_init
+from repro.models.layers import Params, apply_rope, dense_init, scale
 
 NEG_INF = -2.0e38
 
@@ -147,12 +147,15 @@ def attention_full(params: Params, x: jax.Array, *, num_heads: int,
                    num_kv_heads: int, head_dim: int, rope_theta: float,
                    is_global=True, window: int = 0, causal: bool = True,
                    use_rope: bool = True,
-                   positions: Optional[jax.Array] = None) -> jax.Array:
-    """Self-attention over the full sequence. x: [B, S, d] -> [B, S, d]."""
+                   positions: Optional[jax.Array] = None,
+                   key_scale: float = 1.0) -> jax.Array:
+    """Self-attention over the full sequence. x: [B, S, d] -> [B, S, d].
+    ``key_scale`` multiplies the keys (Falcon-H1's ``key_multiplier``)."""
     B, S, _ = x.shape
     G = num_heads // num_kv_heads
     q = _split_heads(x @ params["wq"], num_heads, head_dim)
-    k = _split_heads(x @ params["wk"], num_kv_heads, head_dim)
+    k = _split_heads(scale(x @ params["wk"], key_scale), num_kv_heads,
+                     head_dim)
     v = _split_heads(x @ params["wv"], num_kv_heads, head_dim)
     if positions is None:
         positions = jnp.arange(S)[None, :]
@@ -177,7 +180,7 @@ def attention_decode(params: Params, x: jax.Array, k_cache: jax.Array,
                      num_kv_heads: int, head_dim: int, rope_theta: float,
                      is_global=True, window: int = 0,
                      use_rope: bool = True,
-                     k_scale=None, v_scale=None):
+                     k_scale=None, v_scale=None, key_scale: float = 1.0):
     """One-token decode against a KV cache.
 
     x: [B, 1, d]; k_cache/v_cache: [B, Hkv, S, hd]; pos: int32 [B] — the
@@ -198,7 +201,8 @@ def attention_decode(params: Params, x: jax.Array, k_cache: jax.Array,
     G = num_heads // num_kv_heads
     pos = jnp.broadcast_to(jnp.asarray(pos), (B,))
     q = _split_heads(x @ params["wq"], num_heads, head_dim)     # [B,1,H,hd]
-    k = _split_heads(x @ params["wk"], num_kv_heads, head_dim)  # [B,1,Hkv,hd]
+    k = _split_heads(scale(x @ params["wk"], key_scale), num_kv_heads,
+                     head_dim)                                  # [B,1,Hkv,hd]
     v = _split_heads(x @ params["wv"], num_kv_heads, head_dim)
     posb = pos[:, None]
     if use_rope:

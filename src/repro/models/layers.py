@@ -44,6 +44,23 @@ def rmsnorm(x: jax.Array, gamma: jax.Array, eps: float = 1e-5) -> jax.Array:
     return ((xf * scale) * (1.0 + gamma.astype(jnp.float32))).astype(x.dtype)
 
 
+def scale(x: jax.Array, m: float) -> jax.Array:
+    """x · m computed in fp32 and returned in x's dtype (a bf16 constant
+    such as 0.0375 would be off by 0.3%); no op where m is 1, the default
+    of every multiplier (configs/base.py ``Multipliers``)."""
+    if m == 1.0:
+        return x
+    return (x.astype(jnp.float32) * m).astype(x.dtype)
+
+
+def scale_embedding(x: jax.Array, d_model: int, multiplier=None):
+    """The token embedding's scale: the config's multiplier, or the
+    repository's sqrt(d_model) where it gives none."""
+    if multiplier is None:
+        return x * jnp.asarray(jnp.sqrt(d_model), x.dtype)
+    return scale(x, multiplier)
+
+
 def init_rmsnorm(d: int, dtype) -> jax.Array:
     # stored as the deviation from 1.0 (gemma convention); init to zeros.
     return jnp.zeros((d,), dtype)
@@ -136,10 +153,11 @@ def init_mlp(key, d_model: int, d_ff: int, dtype) -> Params:
     }
 
 
-def mlp(params: Params, x: jax.Array) -> jax.Array:
-    gate = jax.nn.silu(x @ params["w_gate"])
+def mlp(params: Params, x: jax.Array, gate_scale: float = 1.0,
+        down_scale: float = 1.0) -> jax.Array:
+    gate = jax.nn.silu(scale(x @ params["w_gate"], gate_scale))
     up = x @ params["w_up"]
-    return (gate * up) @ params["w_down"]
+    return scale((gate * up) @ params["w_down"], down_scale)
 
 
 # ---------------------------------------------------------------------------

@@ -14,7 +14,8 @@ from repro.configs.base import InputShape, ModelConfig
 from repro.distributed.hints import constrain
 from repro.models import transformer as tfm
 from repro.models.layers import (Params, embed_init, init_rmsnorm, rmsnorm,
-                                 sinusoidal_positions, softmax_cross_entropy)
+                                 scale, scale_embedding, sinusoidal_positions,
+                                 softmax_cross_entropy)
 
 Cache = Dict[str, Any]
 
@@ -70,14 +71,14 @@ class Model:
         return jnp.asarray(self.cfg.global_layer_flags())
 
     def _embed(self, params: Params, tokens: jax.Array) -> jax.Array:
-        x = params["embed"][tokens]
-        return x * jnp.asarray(jnp.sqrt(self.cfg.d_model), x.dtype)
+        return scale_embedding(params["embed"][tokens], self.cfg.d_model,
+                               self.cfg.mult.embedding)
 
     def _logits(self, params: Params, x: jax.Array) -> jax.Array:
         x = rmsnorm(x, params["final_norm"], self.cfg.norm_eps)
         if self.cfg.tie_embeddings:
-            return x @ params["embed"].T
-        return x @ params["unembed"]
+            return scale(x @ params["embed"].T, self.cfg.mult.lm_head)
+        return scale(x @ params["unembed"], self.cfg.mult.lm_head)
 
     def _encode(self, params: Params, frames: jax.Array) -> jax.Array:
         """Whisper encoder over stubbed frame embeddings [B, T, d]."""
@@ -201,10 +202,9 @@ class Model:
                     (L, batch, cfg.num_kv_heads, seq_len, 1), self.dtype)
         if cfg.has_ssm:
             s = cfg.ssm
-            d_inner = s.expand * cfg.d_model
             H = s.num_heads(cfg.d_model)
             layers["conv"] = jnp.zeros(
-                (L, batch, s.d_conv - 1, d_inner + 2 * s.d_state), self.dtype)
+                (L, batch, s.d_conv - 1, s.conv_dim(cfg.d_model)), self.dtype)
             layers["h"] = jnp.zeros((L, batch, H, s.head_dim, s.d_state),
                                     jnp.float32)
         if cfg.is_encdec:

@@ -18,7 +18,8 @@ from repro.distributed.hints import constrain
 from repro.models import attention as attn
 from repro.models import moe as moe_lib
 from repro.models import ssm as ssm_lib
-from repro.models.layers import Params, init_mlp, init_rmsnorm, mlp, rmsnorm
+from repro.models.layers import (Params, init_mlp, init_rmsnorm, mlp, rmsnorm,
+                                 scale)
 
 Cache = Dict[str, Any]
 
@@ -58,20 +59,36 @@ def init_stacked_blocks(key, cfg: ModelConfig, dtype, num_layers=None) -> Params
 # per-layer forward (full-sequence)
 # ---------------------------------------------------------------------------
 
+def combine_mixers(cfg: ModelConfig, a: jax.Array, s: jax.Array
+                   ) -> jax.Array:
+    """THE hybrid combine rule: attention_out · attention + ssm_out · SSM
+    (``cfg.mult``; Falcon-H1's µP multipliers, Hymba's mean as 0.5 and
+    0.5). The decode template (core/jit.py) calls it too."""
+    mu = cfg.mult
+    return scale(a, mu.attention_out) + scale(s, mu.ssm_out)
+
+
+def ffn(p: Params, h2: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """The dense SwiGLU FFN with the config's gate and down multipliers."""
+    return mlp(p["mlp"], h2, cfg.mult.mlp_gate, cfg.mult.mlp_down)
+
+
+def _attention_kw(cfg: ModelConfig, is_global) -> Dict[str, Any]:
+    return dict(num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
+                head_dim=cfg.resolved_head_dim, rope_theta=cfg.rope_theta,
+                is_global=is_global, window=cfg.window_size,
+                use_rope=(cfg.arch_type != "audio"), key_scale=cfg.mult.key)
+
+
 def _mixer_full(p: Params, h: jax.Array, cfg: ModelConfig, is_global) -> jax.Array:
     """Token mixer (attention and/or SSM) on the normed input, full sequence."""
-    hd = cfg.resolved_head_dim
     if cfg.arch_type == "ssm":
         return ssm_lib.ssd_chunked(p["mamba"], h, cfg.ssm)
-    a = attn.attention_full(
-        p["attn"], h, num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
-        head_dim=hd, rope_theta=cfg.rope_theta, is_global=is_global,
-        window=cfg.window_size, causal=True,
-        use_rope=(cfg.arch_type != "audio"))
+    a = attn.attention_full(p["attn"], scale(h, cfg.mult.attention_in),
+                            causal=True, **_attention_kw(cfg, is_global))
     if cfg.arch_type == "hybrid":
-        s = ssm_lib.ssd_chunked(p["mamba"], h, cfg.ssm)
-        # Hymba fuses the parallel attention and SSM head outputs by mean
-        return 0.5 * (a + s)
+        return combine_mixers(cfg, a, ssm_lib.ssd_chunked(p["mamba"], h,
+                                                          cfg.ssm))
     return a
 
 
@@ -89,7 +106,7 @@ def block_full(p: Params, x: jax.Array, cfg: ModelConfig, is_global
         y, aux = moe_lib.moe_ffn(p["moe"], h2.reshape(B * S, d), cfg.moe)
         y = y.reshape(B, S, d)
     else:
-        y = mlp(p["mlp"], h2)
+        y = ffn(p, h2, cfg)
     return x + y, aux
 
 
@@ -122,7 +139,8 @@ def stack_full(stacked: Params, x: jax.Array, cfg: ModelConfig,
 def _project_kv(p: Params, h: jax.Array, cfg: ModelConfig,
                 positions: jax.Array) -> Tuple[jax.Array, jax.Array]:
     hd = cfg.resolved_head_dim
-    k = (h @ p["attn"]["wk"]).reshape(h.shape[0], h.shape[1], cfg.num_kv_heads, hd)
+    k = scale(h @ p["attn"]["wk"], cfg.mult.key).reshape(
+        h.shape[0], h.shape[1], cfg.num_kv_heads, hd)
     v = (h @ p["attn"]["wv"]).reshape(h.shape[0], h.shape[1], cfg.num_kv_heads, hd)
     if cfg.arch_type != "audio":
         from repro.models.layers import apply_rope
@@ -147,18 +165,15 @@ def stack_prefill(stacked: Params, x: jax.Array, cfg: ModelConfig,
             out["conv"], out["h"] = st["conv"], st["h"]
             x = x + y
         else:
-            out["k"], out["v"] = _project_kv(p, h, cfg, positions)
-            a = attn.attention_full(
-                p["attn"], h, num_heads=cfg.num_heads,
-                num_kv_heads=cfg.num_kv_heads, head_dim=cfg.resolved_head_dim,
-                rope_theta=cfg.rope_theta, is_global=flag,
-                window=cfg.window_size, causal=True,
-                use_rope=(cfg.arch_type != "audio"))
+            ha = scale(h, cfg.mult.attention_in)
+            out["k"], out["v"] = _project_kv(p, ha, cfg, positions)
+            a = attn.attention_full(p["attn"], ha, causal=True,
+                                    **_attention_kw(cfg, flag))
             if cfg.arch_type == "hybrid":
                 y, st = ssm_lib.ssd_chunked(p["mamba"], h, cfg.ssm,
                                             return_state=True)
                 out["conv"], out["h"] = st["conv"], st["h"]
-                a = 0.5 * (a + y)
+                a = combine_mixers(cfg, a, y)
             x = x + a
             h2 = rmsnorm(x, p["ln2"], cfg.norm_eps)
             if cfg.has_moe and cfg.arch_type != "hybrid":
@@ -166,7 +181,7 @@ def stack_prefill(stacked: Params, x: jax.Array, cfg: ModelConfig,
                 x = x + y2.reshape(h2.shape)
                 aux = aux + a2
             else:
-                x = x + mlp(p["mlp"], h2)
+                x = x + ffn(p, h2, cfg)
             return (x, aux), out
         return (x, aux), out
 
@@ -190,7 +205,6 @@ def stack_decode(stacked: Params, x: jax.Array, cache: Cache, pos: jax.Array,
     XLA rebuild the full [L, ...] buffer every iteration (§Perf L3:
     327 GB/step of stacked-cache copies measured on llama4 decode_32k).
     """
-    hd = cfg.resolved_head_dim
     L = cfg.num_layers
 
     def body(carry, layer):
@@ -207,25 +221,22 @@ def stack_decode(stacked: Params, x: jax.Array, cache: Cache, pos: jax.Array,
             new_c.update(st)
             x = x + y
         else:
-            kw = dict(num_heads=cfg.num_heads,
-                      num_kv_heads=cfg.num_kv_heads, head_dim=hd,
-                      rope_theta=cfg.rope_theta, is_global=flag,
-                      window=cfg.window_size,
-                      use_rope=(cfg.arch_type != "audio"))
+            kw = _attention_kw(cfg, flag)
+            ha = scale(h, cfg.mult.attention_in)
             if "k_scale" in c:   # int8 KV cache (§Perf K1)
                 a, nk, nv, nks, nvs = attn.attention_decode(
-                    p["attn"], h, c["k"], c["v"], pos,
+                    p["attn"], ha, c["k"], c["v"], pos,
                     k_scale=c["k_scale"], v_scale=c["v_scale"], **kw)
                 new_c["k_scale"], new_c["v_scale"] = nks, nvs
             else:
                 a, nk, nv = attn.attention_decode(
-                    p["attn"], h, c["k"], c["v"], pos, **kw)
+                    p["attn"], ha, c["k"], c["v"], pos, **kw)
             new_c["k"], new_c["v"] = nk, nv
             if cfg.arch_type == "hybrid":
                 y, st = ssm_lib.ssd_decode_step(
                     p["mamba"], h, {"conv": c["conv"], "h": c["h"]}, cfg.ssm)
                 new_c.update(st)
-                a = 0.5 * (a + y)
+                a = combine_mixers(cfg, a, y)
             x = x + a
             h2 = rmsnorm(x, p["ln2"], cfg.norm_eps)
             if cfg.has_moe and cfg.arch_type != "hybrid":
@@ -233,7 +244,7 @@ def stack_decode(stacked: Params, x: jax.Array, cache: Cache, pos: jax.Array,
                 y2, _ = moe_lib.moe_ffn(p["moe"], h2.reshape(B, -1), cfg.moe)
                 x = x + y2.reshape(h2.shape)
             else:
-                x = x + mlp(p["mlp"], h2)
+                x = x + ffn(p, h2, cfg)
         cstack = {k: jax.lax.dynamic_update_index_in_dim(
             cstack[k], new_c[k].astype(cstack[k].dtype), li, 0)
             for k in cstack}

@@ -57,15 +57,18 @@ Arch-support matrix (which path each tenant takes in vliw mode):
                                                                  (+ all-to-all)
   ssm         KernelProgram          analytic                    home device
               (scan recurrence glue)
-  hybrid      monolithic batched     analytic                    home device
+  hybrid      KernelProgram          analytic                    home device
+              (attention + SSM
+              heads in one scanned
+              body per sub-stack)
   audio       monolithic batched     analytic                    home device
   int8-KV     monolithic batched     analytic                    home device
   (any arch)
   ==========  =====================  ==========================  ===============
 
 KernelProgram rows flow through admission → EDF scheduling → clustering →
-coalesced dispatch (``JitStats.nondense_programs`` counts the MoE/SSM
-ones); "monolithic batched" rows run ``Model.decode_step`` inside the same
+coalesced dispatch (``JitStats.nondense_programs`` counts the MoE, SSM
+and hybrid ones); "monolithic batched" rows run ``Model.decode_step`` inside the same
 event loop, serialized on the virtual clock. Baseline modes ("time",
 "batched") always run monolithic steps — that asymmetry IS the experiment.
 
@@ -154,9 +157,11 @@ from repro.core.dispatch import device_weight_budget
 from repro.core.jit import (JitStats, KernelProgram, VLIWJit,
                             build_dense_decode_template,
                             build_dense_prefill_template,
+                            build_hybrid_decode_template,
                             build_moe_decode_template,
                             build_ssm_decode_template,
-                            dense_program_cache_key, moe_program_cache_key,
+                            dense_program_cache_key,
+                            hybrid_program_cache_key, moe_program_cache_key,
                             prefill_bucket, prefill_program_cache_key,
                             ssm_program_cache_key)
 from repro.core.kernelspec import gemm_population
@@ -755,11 +760,12 @@ class ServingEngine:
     # ------------------------------------------------------------------
     def _jit_capable(self, t: Tenant) -> bool:
         # layerwise kernel programs cover dense/vlm GQA decode, MoE decode
-        # (router glue + per-expert GemmStages) and SSM decode (selective-
-        # scan glue) over bf16/f32 caches; int8-KV tenants (and hybrid /
-        # encdec archs) take the monolithic batched step — see the
-        # arch-support matrix in the module docstring
-        return t.cfg.arch_type in ("dense", "vlm", "moe", "ssm") \
+        # (router glue + per-expert GemmStages), SSM decode (selective-
+        # scan glue) and hybrid decode (attention and SSM heads in one
+        # scanned body) over bf16/f32 caches; int8-KV tenants (and encdec
+        # archs) take the monolithic batched step — see the arch-support
+        # matrix in the module docstring
+        return t.cfg.arch_type in ("dense", "vlm", "moe", "ssm", "hybrid") \
             and not getattr(t.model, "kv_quant", False)
 
     def _prefill_capable(self, t: Tenant) -> bool:
@@ -894,6 +900,12 @@ class ServingEngine:
                                         stacked=stacked)
             build = lambda: build_ssm_decode_template(  # noqa: E731
                 t.model, t.params, batch, stacked=stacked)
+        elif arch == "hybrid":
+            # stacked in every regime: the hybrid template has no
+            # per-layer emission
+            key = hybrid_program_cache_key(t.model, t.params, batch, t.cache)
+            build = lambda: build_hybrid_decode_template(  # noqa: E731
+                t.model, t.params, batch)
         else:
             key = dense_program_cache_key(t.model, t.params, batch, t.cache,
                                           stacked=stacked)
@@ -1091,7 +1103,7 @@ class ServingEngine:
                     and t.active_slots():
                 prog = self._build_program(t, st.stream_ids[name],
                                            now[d])
-                if t.cfg.arch_type in ("moe", "ssm"):
+                if t.cfg.arch_type in ("moe", "ssm", "hybrid"):
                     session.stats.nondense_programs += 1
                 session.stats.decode_programs += 1
                 session.stats.decode_rows += len(t.active_slots())

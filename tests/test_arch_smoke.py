@@ -83,6 +83,7 @@ def test_full_config_matches_assignment(arch):
         "hymba-1.5b": (32, 1600, 25, 5, 5504, 32001),
         "llama4-maverick-400b-a17b": (48, 5120, 40, 8, 8192, 202048),
         "gemma3-1b": (26, 1152, 4, 1, 6912, 262144),
+        "falcon-h1-34b": (72, 5120, 20, 4, 21504, 261120),
     }[arch]
     got = (cfg.num_layers, cfg.d_model, cfg.num_heads, cfg.num_kv_heads,
            cfg.d_ff, cfg.vocab_size)
@@ -97,3 +98,6 @@ def test_full_config_matches_assignment(arch):
         assert cfg.ssm.d_state == 16
     if arch == "gemma3-1b":
         assert cfg.window_size == 1024 and cfg.global_every == 6
+    if arch == "falcon-h1-34b":
+        assert (cfg.ssm.d_state, cfg.ssm.n_groups, cfg.ssm.inner(5120),
+                cfg.ssm.num_heads(5120)) == (256, 2, 4096, 32)

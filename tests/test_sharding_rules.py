@@ -83,4 +83,4 @@ def test_skip_matrix_documented():
     assert not pair_is_supported("whisper-tiny", "long_500k")
     n_supported = sum(pair_is_supported(a, s) for a in ARCH_IDS
                       for s in INPUT_SHAPES)
-    assert n_supported == 34
+    assert n_supported == 37
