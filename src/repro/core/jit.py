@@ -232,11 +232,11 @@ class StackedGemmStage:
     operands: List[StackedOperand]
     layers: int                    # hi - lo
     # run(env, {operand tag -> padded stacked array}, executor): executes
-    # the scan and writes results (residual stream, cache updates) to env;
-    # a prefill body returns its (Sp, bm, bn, bk) for
-    # JitStats.prefill_bodies, every other body None
+    # the scan, writes results (residual stream, cache updates) to env and
+    # returns its rows and GEMM tile (m, bm, bn, bk), which the session
+    # counts in JitStats.prefill_bodies or decode_bodies
     run: Callable[[Dict[str, Any], Dict[str, jax.Array],
-                   SuperkernelExecutor], Optional[Tuple[int, ...]]]
+                   SuperkernelExecutor], Tuple[int, int, int, int]]
     # declared access sets (see GemmStage.reads/writes): a scanned body
     # reads the residual stream + cache slices and writes the residual
     # stream + its cache-update chunk
@@ -264,12 +264,43 @@ def _scan_gemm(a: jax.Array, w_pad: jax.Array, n_real: int, *, bm: int,
     K, N = int(w_pad.shape[-2]), int(w_pad.shape[-1])
     m_tiles = _tile_bucket([m], bm)
     ap = jnp.pad(a, ((0, m_tiles * bm - m), (0, K - int(a.shape[1]))))
-    # an aligned envelope (dispatch.stacked_envelope) is a multiple of 512,
-    # not a power of two: a larger live-tuned tile shrinks to a divisor
     out = coalesced_gemm(ap, w_pad[None], jnp.zeros((m_tiles,), jnp.int32),
-                         bm=bm, bn=math.gcd(min(bn, N), N),
-                         bk=math.gcd(min(bk, K), K), interpret=interpret)
+                         bm=bm, bn=_panel(bn, N), bk=_panel(bk, K),
+                         interpret=interpret)
     return out[:m, :n_real]
+
+
+def _panel(b: int, extent: int) -> int:
+    """A tile's block ``b`` along one weight extent, shrunk to a divisor of
+    it: an aligned envelope (dispatch.stacked_envelope) is a multiple of
+    512, not a power of two, and a power-of-two block wider than 512
+    takes the largest power of two that divides the envelope."""
+    return math.gcd(min(b, extent), extent)
+
+
+def _body_jit(jits: Dict[Tuple, Callable], jit: Callable[[Tuple], Callable],
+              tile: Tuple[int, int, int], padded: Dict[str, jax.Array],
+              ex: SuperkernelExecutor, block: Optional[BlockConfig]
+              ) -> Tuple[Callable, Tuple[int, int, int]]:
+    """The jitted scan of one stacked body and the GEMM tile it runs at:
+    the body's own ``tile``, or the live-tuned ``block``
+    (JitSession._run_stacked) where one is given. Each distinct tile is
+    jitted once by ``jit`` (bm, bn, bk, interpret), memoized in ``jits``
+    for the template's lifetime, after every operand is checked against
+    VMEM at it. Callers pass ``jax.jit`` of their maker in a lambda, the
+    form in which ``analysis/lint.py`` sees the body as jit-rooted."""
+    if block is not None:
+        tile = (block.bm, block.bn, block.bk)
+    key = tuple(tile) + (ex.interpret,)
+    fn = jits.get(key)
+    if fn is None:
+        bm, bn, bk = tile
+        for w in padded.values():
+            K, N = int(w.shape[-2]), int(w.shape[-1])
+            check_vmem(bm, _panel(bn, N), _panel(bk, K),
+                       dtype_bytes=w.dtype.itemsize, interpret=ex.interpret)
+        fn = jits[key] = jit(key)
+    return fn, tuple(tile)
 
 
 def _stack_slice(arr: jax.Array, lo: int, hi: int) -> jax.Array:
@@ -870,9 +901,9 @@ def _stacked_dense_body_stage(model, params, B: int, lo: int, hi: int, *,
         ]
     ln1s = _stack_slice(blocks["ln1"], lo, hi)
     ln2s = _stack_slice(blocks["ln2"], lo, hi)
-    # one jitted scan per executor block signature, memoized for the
-    # template's lifetime (templates live in the JIT's plan cache, so the
-    # steady state reuses one compiled executable)
+    # one jitted scan per GEMM tile, memoized for the template's lifetime
+    # (templates live in the JIT's plan cache, so the steady state reuses
+    # one compiled executable)
     jits: Dict[Tuple, Callable] = {}
 
     def make_scan(bm: int, bn: int, bk: int, interpret: bool):
@@ -939,15 +970,13 @@ def _stacked_dense_body_stage(model, params, B: int, lo: int, hi: int, *,
     if moe:
         aux["router"] = routers
 
+    # one m-tile covers the expert GEMMs' capacity rows as well
+    rows = max(B, C) if moe else B
+
     def run(env, padded, ex, block=None):
-        # live-tuned tile override (JitSession._run_stacked): keyed beside
-        # the executor defaults, so each distinct tuned config compiles
-        # its scan body once and stable configs never retrace
-        key = (ex.bm, ex.bn, ex.bk, ex.interpret) if block is None else \
-            (block.bm, block.bn, block.bk, ex.interpret)
-        fn = jits.get(key)
-        if fn is None:
-            fn = jits[key] = jax.jit(make_scan(*key))
+        fn, tile = _body_jit(
+            jits, lambda k: jax.jit(make_scan(*k)),
+            decode_tile(rows, ex.bk), padded, ex, block)
         cache = env["cache"]
         x, (kc_new, vc_new) = fn(env["x"], jnp.asarray(cache["pos"]),
                                  cache["layers"]["k"], cache["layers"]["v"],
@@ -955,6 +984,7 @@ def _stacked_dense_body_stage(model, params, B: int, lo: int, hi: int, *,
         env["x"] = x
         env["new_layers"]["k"].append(kc_new)
         env["new_layers"]["v"].append(vc_new)
+        return (B,) + tile
 
     return StackedGemmStage(
         tag=f"body_{lo}_{hi}",
@@ -1260,20 +1290,16 @@ def _build_stacked_ssm_decode_template(model, params, batch: int
     aux = {"ln1": ln1s, "mamba": mamba_rest}
 
     def run(env, padded, ex, block=None):
-        # live-tuned tile override (JitSession._run_stacked): keyed beside
-        # the executor defaults, so each distinct tuned config compiles
-        # its scan body once and stable configs never retrace
-        key = (ex.bm, ex.bn, ex.bk, ex.interpret) if block is None else \
-            (block.bm, block.bn, block.bk, ex.interpret)
-        fn = jits.get(key)
-        if fn is None:
-            fn = jits[key] = jax.jit(make_scan(*key))
+        fn, tile = _body_jit(
+            jits, lambda k: jax.jit(make_scan(*k)),
+            decode_tile(B, ex.bk), padded, ex, block)
         cache = env["cache"]
         x, (conv_new, h_new) = fn(env["x"], cache["layers"]["conv"],
                                   cache["layers"]["h"], padded, aux)
         env["x"] = x
         env["new_layers"]["conv"].append(conv_new)
         env["new_layers"]["h"].append(h_new)
+        return (B,) + tile
 
     stages.append(StackedGemmStage(
         tag=f"body_{lo}_{hi}",
@@ -1487,17 +1513,16 @@ def _stacked_hybrid_body_stage(model, params, B: int, lo: int, hi: int
         return hybrid_decode_layers
 
     def run(env, padded, ex, block=None):
-        key = (ex.bm, ex.bn, ex.bk, ex.interpret) if block is None else \
-            (block.bm, block.bn, block.bk, ex.interpret)
-        fn = jits.get(key)
-        if fn is None:
-            fn = jits[key] = jax.jit(make_scan(*key))
+        fn, tile = _body_jit(
+            jits, lambda k: jax.jit(make_scan(*k)),
+            decode_tile(B, ex.bk), padded, ex, block)
         lay = env["cache"]["layers"]
         x, new = fn(env["x"], jnp.asarray(env["cache"]["pos"]), lay["k"],
                     lay["v"], lay["conv"], lay["h"], padded, aux)
         env["x"] = x
         for name, arr in zip(("k", "v", "conv", "h"), new):
             env["new_layers"][name].append(arr)
+        return (B,) + tile
 
     return StackedGemmStage(
         tag=f"body_{lo}_{hi}",
@@ -1530,12 +1555,11 @@ def _hybrid_head_stage(model, params, B: int) -> StackedGemmStage:
         return hybrid_decode_head
 
     def run(env, padded, ex, block=None):
-        key = (ex.bm, ex.bn, ex.bk, ex.interpret) if block is None else \
-            (block.bm, block.bn, block.bk, ex.interpret)
-        fn = jits.get(key)
-        if fn is None:
-            fn = jits[key] = jax.jit(make_head(*key))
+        fn, tile = _body_jit(
+            jits, lambda k: jax.jit(make_head(*k)),
+            decode_tile(B, ex.bk), padded, ex, block)
         env["logits"] = fn(env["x"], padded, params["final_norm"])
+        return (B,) + tile
 
     return StackedGemmStage(
         tag="head", weight_key=weight_key(cfg.name, pid, "head"),
@@ -1611,6 +1635,27 @@ def prefill_tile(m: int, bk: int) -> Tuple[int, int, int]:
     bucket, a power of two): min(m, PREFILL_BM_MAX) rows and PREFILL_BN
     columns a tile, at the executor's ``bk``."""
     return min(m, PREFILL_BM_MAX), PREFILL_BN, bk
+
+
+# The stacked decode bodies' GEMM tile. At the executor's tile (8, 128,
+# 512) a body over 16 slots runs two m-tiles, so it streams every weight
+# panel twice a step, in one Pallas grid step per 128 KB panel. One m-tile
+# of the batch's own rows (up to 128, a v5e MXU's height) streams each
+# panel once, and a wide panel cuts the grid steps. ``_scan_gemm`` clamps
+# bn to a divisor of each aligned envelope (10752 and 2560 take 512;
+# 11264, 21504 and 261120 at most 1024), so 1024 is the widest panel the
+# largest operands can take: 2048 gives them the same tile. bk stays the
+# executor's, so the K blocks are summed in the same order.
+DECODE_BM_MAX = 128
+DECODE_BN = 1024
+
+
+def decode_tile(m: int, bk: int) -> Tuple[int, int, int]:
+    """GEMM tile (bm, bn, bk) of a stacked decode body over ``m`` rows (the
+    slotted batch, or an MoE expert's capacity where larger): ``m`` rounded
+    up to a multiple of 8, at most DECODE_BM_MAX, so that one m-tile
+    covers the batch; DECODE_BN columns; the executor's ``bk``."""
+    return min(-(-m // 8) * 8, DECODE_BM_MAX), DECODE_BN, bk
 
 
 def _causal_prefill_attend(cfg: ModelConfig, Sp: int, q_flat, k_flat,
@@ -1710,20 +1755,9 @@ def _stacked_prefill_body_stage(model, params, Sp: int, lo: int, hi: int
     aux = {"ln1": ln1s, "ln2": ln2s}
 
     def run(env, padded, ex, block=None):
-        # the live-tuned tile (JitSession._run_stacked) overrides the
-        # bucket's own; each distinct tile compiles the scan body once
-        tile = prefill_tile(Sp, ex.bk) if block is None else \
-            (block.bm, block.bn, block.bk)
-        key = tile + (ex.interpret,)
-        fn = jits.get(key)
-        if fn is None:
-            bm, bn, bk = tile
-            for w in padded.values():
-                K, N = int(w.shape[-2]), int(w.shape[-1])
-                check_vmem(bm, min(bn, N), min(bk, K),
-                           dtype_bytes=w.dtype.itemsize,
-                           interpret=ex.interpret)
-            fn = jits[key] = jax.jit(make_scan(*key))
+        fn, tile = _body_jit(
+            jits, lambda k: jax.jit(make_scan(*k)),
+            prefill_tile(Sp, ex.bk), padded, ex, block)
         x, (k_ys, v_ys) = fn(env["x"], env["positions"], padded, aux)
         env["x"] = x
         env["new_layers"]["k"].append(k_ys)
@@ -1990,6 +2024,11 @@ class JitStats:
     # Counter: ``+`` merges, ``-`` gives an epoch's delta
     prefill_bodies: collections.Counter = dataclasses.field(
         default_factory=collections.Counter)
+    # stacked decode bodies dispatched (the head of a hybrid step among
+    # them), by (B, bm, bn, bk): the slotted batch and the GEMM tile the
+    # body ran at (``decode_tile``); merged like ``prefill_bodies``
+    decode_bodies: collections.Counter = dataclasses.field(
+        default_factory=collections.Counter)
 
     @property
     def mean_group(self) -> float:
@@ -2243,10 +2282,11 @@ class JitSession:
                      block: Optional[BlockConfig] = None) -> None:
         """Dispatch a coalesced group of layer-stacked body ops: pack each
         op's stacked weight operands through the executor's persistent
-        cache, then run the scanned bodies back-to-back. ``block``
-        overrides the executor's default tile for the scanned GEMMs (the
+        cache, then run the scanned bodies back-to-back and count each in
+        ``prefill_bodies`` or ``decode_bodies`` at the tile it ran.
+        ``block`` overrides each body's own tile for the scanned GEMMs (the
         live-tuned config of the plan) — each distinct config compiles its
-        own scan body once, keyed beside the executor defaults."""
+        own scan body once, keyed beside the body's tile."""
         ex = self.jit.executor
         for op in ops:
             prog, st = self.live.pop(op.op_id)
@@ -2282,9 +2322,10 @@ class JitSession:
                 else:
                     ex.stats.weight_hits += 1
                 ex.stats.dispatches += 1
-            prefill = st.run(prog.env, padded, ex, block)
-            if prefill is not None:
-                self.stats.prefill_bodies[prefill] += 1
+            tile = st.run(prog.env, padded, ex, block)
+            bodies = self.stats.prefill_bodies if prog.kind == "prefill" \
+                else self.stats.decode_bodies
+            bodies[tile] += 1
             prog.pc += 1
             nxt = prog.advance_glue()
             if nxt is None:

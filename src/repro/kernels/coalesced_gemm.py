@@ -25,13 +25,35 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.backend import interpret_default
 
 
-def _kernel(gid_ref, a_ref, b_ref, o_ref, acc_ref, *, nk: int):
+# The (rows, columns) of the product interpret mode computes at a time.
+SUB_BLOCK = (8, 128)
+
+
+def _kernel(gid_ref, a_ref, b_ref, o_ref, acc_ref, *, nk: int,
+            interpret: bool):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(a_ref[...], b_ref[0],
-                            preferred_element_type=jnp.float32)
+    bm, bn = acc_ref.shape
+    sm, sn = min(SUB_BLOCK[0], bm), min(SUB_BLOCK[1], bn)
+    if not interpret or (sm, sn) == (bm, bn) or bm % sm or bn % sn:
+        acc_ref[...] += jnp.dot(a_ref[...], b_ref[0],
+                                preferred_element_type=jnp.float32)
+    else:
+        # The MXU sums each output element's bk products in one order
+        # whatever the block's shape; XLA's CPU dot does not, its order
+        # changes with the shape. So interpret mode computes the block in
+        # SUB_BLOCK pieces, each one dot of the one shape, and every tile
+        # that shares bk gives the bits of every other, as on the chip.
+        def piece(t, carry):
+            i = pl.ds(pl.multiple_of(t // (bn // sn) * sm, sm), sm)
+            j = pl.ds(pl.multiple_of(t % (bn // sn) * sn, sn), sn)
+            acc_ref[i, j] += jnp.dot(a_ref[i, :], b_ref[0, :, j],
+                                     preferred_element_type=jnp.float32)
+            return carry
+
+        jax.lax.fori_loop(0, (bm // sm) * (bn // sn), piece, 0)
 
     @pl.when(pl.program_id(2) == nk - 1)
     def _flush():
@@ -60,6 +82,7 @@ def coalesced_gemm(a_packed: jax.Array, b_stacked: jax.Array,
     bk = min(bk, K)
     assert N % bn == 0 and K % bk == 0, (N, bn, K, bk)
     nk = K // bk
+    interpret = interpret_default() if interpret is None else interpret
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -72,11 +95,11 @@ def coalesced_gemm(a_packed: jax.Array, b_stacked: jax.Array,
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
     )
     return pl.pallas_call(
-        functools.partial(_kernel, nk=nk),
+        functools.partial(_kernel, nk=nk, interpret=interpret),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, N), a_packed.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         name="coalesced_gemm",
-        interpret=interpret_default() if interpret is None else interpret,
+        interpret=interpret,
     )(group_ids, a_packed, b_stacked)

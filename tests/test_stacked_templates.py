@@ -14,12 +14,13 @@ import numpy as np
 import pytest
 
 from repro.configs import smoke_config
-from repro.core.jit import (PREFILL_BM_MAX, PREFILL_BN, StackedGemmStage,
-                            VLIWJit, build_dense_decode_template,
+from repro.core.jit import (DECODE_BM_MAX, DECODE_BN, PREFILL_BM_MAX,
+                            PREFILL_BN, StackedGemmStage, VLIWJit,
+                            _panel, build_dense_decode_template,
                             build_dense_prefill_template,
                             build_moe_decode_template,
-                            build_ssm_decode_template, partition_layers,
-                            prefill_bucket, prefill_tile)
+                            build_ssm_decode_template, decode_tile,
+                            partition_layers, prefill_bucket, prefill_tile)
 from repro.models import Model
 from repro.serving import ServeRequest, ServingEngine, Tenant
 
@@ -70,12 +71,16 @@ def _setup(m, params, B, S=12, CL=32):
 # bit-identity: stacked vs per-layer oracle
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("batch", [1, 2, 4])
-@pytest.mark.parametrize("fam", ["dense", "moe", "ssm"])
+@pytest.mark.parametrize(
+    "fam,batch",
+    [(f, b) for f in ("dense", "moe", "ssm") for b in (1, 2, 4)]
+    + [("dense", 16), ("ssm", 16)])
 def test_stacked_decode_bit_identical_to_per_layer(fam, batch, models):
     """The tentpole contract: the scanned layer body computes the SAME
     BITS as the per-layer executor dispatch — logits AND every recurrent
-    cache leaf, over multiple steps (divergence would compound)."""
+    cache leaf, over multiple steps (divergence would compound). At batch
+    16 the body runs one 16-row m-tile (``decode_tile``), the per-layer
+    path two of the executor's 8 rows, at the same bk."""
     m, params = models[fam]
     cache0, tok = _setup(m, params, batch)
     want, want_cache = _decode_steps(DECODE_BUILDERS[fam], m, params,
@@ -230,6 +235,53 @@ def test_engine_counts_prefill_bodies_by_bucket():
     assert delta == {entry(64): 1, entry(128): 2}
     assert sum(delta.values()) == sum(
         r.prompt_len >= eng.prefill_declare_min for r in trace)
+
+
+# ---------------------------------------------------------------------------
+# the decode bodies' GEMM tile
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("m,bm", [(1, 8), (2, 8), (4, 8), (16, 16),
+                                  (64, 64)])
+def test_decode_tile_by_rows(m, bm):
+    """A decode body takes its rows rounded up to a multiple of 8 (one
+    m-tile, up to DECODE_BM_MAX), DECODE_BN columns and the executor's
+    bk."""
+    assert bm <= DECODE_BM_MAX
+    assert decode_tile(m, 512) == (bm, DECODE_BN, 512)
+    assert decode_tile(DECODE_BM_MAX + 1, 512)[0] == DECODE_BM_MAX
+
+
+@pytest.mark.parametrize("n,widest", [(512, 512), (10752, 512),
+                                      (11264, 1024), (261120, 1024)])
+def test_decode_panel_divides_the_envelope(n, widest):
+    """``_scan_gemm`` clamps DECODE_BN to a divisor of each aligned
+    envelope: Mamba2's in_proj (10752 = 512 x 21), Yi-9B's FFN (11264 =
+    1024 x 11) and Falcon-H1's head (261120 = 1024 x 255)."""
+    bn = _panel(DECODE_BN, n)
+    assert n % bn == 0 and bn == min(widest, DECODE_BN)
+
+
+def test_engine_counts_decode_bodies_by_tile():
+    """``JitStats.decode_bodies`` counts one stacked body per decode
+    program at (max_batch, ``decode_tile``), apart from the prefill
+    bodies, and ``-`` of two readings gives the epoch's own."""
+    cfg = smoke_config("yi-9b")
+    assert len(partition_layers(cfg.global_layer_flags())) == 1
+    m = Model(cfg, param_dtype=jnp.float32)
+    eng = ServingEngine([Tenant("a", m, m.init(jax.random.PRNGKey(5)),
+                                cache_len=136, max_batch=2)], mode="vliw")
+    entry = (2, *decode_tile(2, eng.jit.executor.bk))
+    rep = eng.run([ServeRequest(0, "a", 0.0, 20, 3, 10.0)])
+    before = rep.jit.decode_bodies
+    assert before == {entry: rep.jit.decode_programs} and \
+        rep.jit.decode_programs > 0
+    assert all(k[0] == 32 for k in rep.jit.prefill_bodies)
+    rep = eng.run([ServeRequest(i, "a", 0.0, 40, 4, 10.0)
+                   for i in range(2)])
+    delta = rep.jit.decode_bodies - before
+    assert delta == {entry: rep.jit.decode_programs
+                     - before[entry]} and delta[entry] > 0
 
 
 # ---------------------------------------------------------------------------

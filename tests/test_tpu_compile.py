@@ -20,8 +20,9 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core.dispatch import _dispatch_grouped, _dispatch_shared
-from repro.core.jit import _scan_gemm, prefill_tile
+from repro.core.dispatch import (_dispatch_grouped, _dispatch_shared,
+                                 stacked_envelope)
+from repro.core.jit import _scan_gemm, decode_tile, prefill_tile
 from repro.kernels import coalesced_gemv, flash_attention
 
 # Yi-9B (configs/yi_9b.py): d_model, d_ff and d_ff's envelope bucket
@@ -124,6 +125,34 @@ def test_stacked_scan_gemm_prefill_tile(one_chip):
           "up": _spec(one_chip, (4, D, FF_PAD)),
           "down": _spec(one_chip, (4, FF_PAD, D))}
     compiled = jax.jit(scan).lower(_spec(one_chip, (2048, D)), ws).compile()
+    _assert_compiled_for_chip(compiled)
+
+
+def test_stacked_scan_gemm_decode_tile(one_chip):
+    """``_scan_gemm`` over 4 layers of 16 slots at the decode bodies' tile
+    (``decode_tile``), at Falcon-H1-34B's widths: the SSM in projection at
+    its aligned envelope, FFN up and down (5120 -> 21504 -> 5120), then
+    the 5120 x 261120 head, so that a VMEM fault at the wide panel shows
+    here."""
+    d, ff, n_in, vocab = 5120, 21504, 9248, 261120
+    bm, bn, bk = decode_tile(16, 512)
+
+    def gemm(a, w, n):
+        return _scan_gemm(a, w, n, bm=bm, bn=bn, bk=bk, interpret=False)
+
+    def body(x, w):
+        z = gemm(x, w["in_proj"], n_in)
+        up = gemm(x + z[:, :d], w["up"], ff)
+        return gemm(up, w["down"], d), None
+
+    def step(x, w, head):
+        return gemm(jax.lax.scan(body, x, w)[0], head, vocab)
+
+    ws = {"in_proj": _spec(one_chip, (4, d, stacked_envelope(n_in))),
+          "up": _spec(one_chip, (4, d, ff)),
+          "down": _spec(one_chip, (4, ff, d))}
+    compiled = jax.jit(step).lower(_spec(one_chip, (16, d)), ws,
+                                   _spec(one_chip, (d, vocab))).compile()
     _assert_compiled_for_chip(compiled)
 
 
