@@ -19,8 +19,7 @@ from benchmarks import (compiled_autotune_bench, dispatch_bench,
                         moe_coalescing_bench, multi_device_bench,
                         plan_cache_bench,
                         prefill_coalescing_bench, rnn_gemv_coalescing,
-                        roofline_report, stacked_depth_bench,
-                        table1_autotuning)
+                        roofline_report, table1_autotuning)
 
 MODULES = [
     ("fig3", fig3_batch_utilization),
@@ -36,7 +35,6 @@ MODULES = [
     ("prefill_coalescing", prefill_coalescing_bench),
     ("dispatch", dispatch_bench),
     ("moe_coalescing", moe_coalescing_bench),
-    ("stacked_depth", stacked_depth_bench),
     ("multi_device", multi_device_bench),
     ("compiled_autotune", compiled_autotune_bench),
 ]

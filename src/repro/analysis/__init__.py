@@ -33,8 +33,8 @@ and machine-checkable, in three passes:
     closures capturing param arrays as baked constants (the last-ulp
     drift class), plan-cache key functions missing fields that
     ``ProgramTemplate.bind`` does not rebind (the stale-template class),
-    and glue math bypassing the memoized ``_GLUE_JITS`` wrappers (the
-    eager-vs-jitted bit-identity class). Runnable as
+    and glue math called eagerly outside a jitted body (the
+    eager-vs-jitted last-ulp class). Runnable as
     ``python -m repro.analysis.lint [path] [--strict] [--json]``.
 
 Hazard taxonomy (all subclasses of ``HazardViolation``; defined in

@@ -21,16 +21,17 @@ Three rules, each named after the failure it prevents:
     else a template closes over must be captured by its plan-cache key
     or a stale template silently serves the wrong closures. Key
     functions (``*_cache_key``) must reference the known-irreplaceable
-    ingredients: object identity (``id(``), dtype (``.dtype``), cache
-    geometry (``.shape``) and the emission regime (``"stacked"``).
+    ingredients: object identity (``id(``), dtype (``.dtype``) and cache
+    geometry (``.shape``).
 
 ``TH003`` — raw glue math called outside a jitted context.
     Eager execution of the attention/MoE/SSM glue helpers computes
-    different last-ulp bits than the same helper inside a jitted program
-    (the reason ``_GLUE_JITS`` exists). Direct calls to the raw helpers
-    are only legal inside a jit-rooted function chain (the closure some
-    ``jax.jit`` call roots, including jit factories) or in the helper's
-    defining module (the analytic baseline path).
+    different last-ulp bits than the same helper inside a jitted program,
+    and dispatches each of its ops on its own. Direct calls to the raw
+    helpers are only legal inside a jit-rooted function chain (the closure
+    some ``jax.jit`` call roots, including jit factories, such as the
+    stacked templates' scan bodies) or in the helper's defining module
+    (the analytic baseline path).
 
 Jit-rootedness is derived per module: ``@jax.jit`` /
 ``functools.partial(jax.jit, ...)`` decorations, ``jax.jit(name)`` /
@@ -70,7 +71,6 @@ CACHE_KEY_INGREDIENTS = (
     ("id(", "object identity (id(...))"),
     (".dtype", "dtype"),
     (".shape", "cache geometry (.shape)"),
-    ("stacked", "emission regime (\"stacked\")"),
 )
 
 _FUNC_NODES = (ast.FunctionDef, ast.AsyncFunctionDef, ast.Lambda)
@@ -365,8 +365,8 @@ def _check_th003(mod: _Module) -> List[Finding]:
         findings.append(Finding(
             "TH003", str(mod.path), node.lineno, where,
             f"raw glue helper '{callee}' called eagerly (outside any "
-            f"jit-rooted chain) — route it through the memoized "
-            f"_GLUE_JITS wrappers for eager/jitted bit-identity"))
+            f"jit-rooted chain) — call it inside a jitted body (a "
+            f"stacked template's scan) so it runs compiled"))
     return findings
 
 

@@ -96,50 +96,37 @@ def cluster_greedy(shapes: Sequence[GemmShape], max_waste: float = 0.25
 #   * the weight KEY (op.payload[2], attached by JitSession._push_op): ops
 #     sharing one key literally serve the same weight array(s), so the whole
 #     group collapses to a single weight load (the shared-operand regime);
-#   * the EXPERT tag prefix: MoE tenants emit each expert FFN GEMM as its
-#     own stage tagged "expert_*" with the expert index in the weight key,
-#     so the same expert's GEMMs coalesce across tenants (and with dense
-#     FFN GEMMs sharing their (n, k)) — the scenario-diversity win counted
-#     by JitStats.expert_coalesced.
+#   * the EXPERT tag prefix: an MoE tenant's scanned body carries its
+#     expert packs as stacked operands tagged "expert_*" (``is_expert_op``
+#     finds them through op.stack), so a coalesced group holding one
+#     counts in JitStats.expert_coalesced.
 #
-# ``weight_key`` below is THE single key constructor (used by core/jit.py
-# builders and core/dispatch.py matvec): the schema used to be rebuilt
-# ad-hoc at each emission site with the layer index assumed at a fixed
-# tuple position, which would have silently broken shared-operand detection
-# the moment stacked keys (no per-layer index) appeared. The shapes are:
+# ``weight_key`` below is THE single key constructor of the core/jit.py
+# builders (raw matvecs take ``matvec_weight_key``). The shapes are:
 #
-#   per-layer operand   (model, pid, layer:int, name[, expert])
-#   stacked operand     (model, pid, "stack", lo, hi, name[, expert])
+#   stacked operand     (model, pid, "stack", lo, hi, name)
 #   model-level operand (model, pid, name)            e.g. "unembed"
 #   raw matvec          ("matvec"|"matvec-shared", id(w))
 #
-# The "stack" marker cannot collide with the other forms at position 2:
-# per-layer keys hold an int there and model-level keys hold an operand
-# name, which is never the reserved string "stack".
+# The "stack" marker cannot collide with the model-level form at position
+# 2: that holds an operand name, which is never the reserved string
+# "stack".
 
 EXPERT_TAG_PREFIX = "expert_"
 
 
 def weight_key(model_name: str, params_id: int, name: str, *,
-               layer=None, expert=None, stack=None) -> Tuple:
+               stack=None) -> Tuple:
     """Build an operand-identity key (single schema for all emitters).
 
     ``stack=(lo, hi)`` names one stacked operand covering layers
-    [lo, hi) — one key per homogeneous sub-stack, layer index dropped.
-    ``layer`` names a per-layer slice (the stacked_layers=False oracle
-    path). Neither → a model-level operand (tied unembed etc.).
-    ``expert`` appends the MoE expert index in either regime.
+    [lo, hi) — one key per homogeneous sub-stack. Without it the key
+    names a model-level operand (tied unembed etc.).
     """
     if stack is not None:
         lo, hi = stack
-        key: Tuple = (model_name, params_id, "stack", int(lo), int(hi), name)
-    elif layer is not None:
-        key = (model_name, params_id, int(layer), name)
-    else:
-        key = (model_name, params_id, name)
-    if expert is not None:
-        key = key + (int(expert),)
-    return key
+        return (model_name, params_id, "stack", int(lo), int(hi), name)
+    return (model_name, params_id, name)
 
 
 def matvec_weight_key(w, shared: bool = False) -> Tuple:

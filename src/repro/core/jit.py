@@ -16,12 +16,14 @@ into the tenant's slotted cache — so long prompts enter the live op pool
 and coalesce with decode (and other tenants' prefill) traffic instead of
 serializing the device (``JitStats.prefill_coalesced``).
 
-Non-dense tenants are first-class streams too: MoE decode steps compile
-with the router/dispatch as glue and 3·E per-expert FFN ``GemmStage``s
-(``build_moe_decode_template`` — same expert GEMMs coalesce across tenants,
-``JitStats.expert_coalesced``), and SSM (Mamba-2/SSD) decode steps compile
-with the in/out projections declared and the selective-scan recurrence as
-glue (``build_ssm_decode_template``) — the paper's heterogeneous-tenant
+Non-dense tenants are first-class streams too: MoE decode steps declare
+their expert packs as stacked operands and run the router, capacity
+dispatch and combine inside the scanned body
+(``build_moe_decode_template``, ``JitStats.expert_coalesced``), SSM
+(Mamba-2/SSD) decode steps declare the in/out projections and run the
+selective-scan recurrence inside the body (``build_ssm_decode_template``),
+and hybrid steps run both side by side in every layer
+(``build_hybrid_decode_template``) — the paper's heterogeneous-tenant
 multiplexing scenario, not just same-family dense fleets.
 
 The runtime is a **virtual-time event loop**, not a round barrier. A
@@ -59,19 +61,18 @@ bucketed to powers of two, and the whole pack→kernel→unpack dispatch is
 one jitted executable — so a stable trace runs zero-copy and zero-retrace
 after warmup (``JitStats.dispatch``).
 
-**Layer-stacked templates (scan-over-layers).** By default
-(``stacked_layers=True`` throughout) the builders emit ONE scanned layer
-body per homogeneous sub-stack of layers instead of ~6 stages per layer:
-the params tree already stores weights stacked along a leading layer axis,
-so a ``StackedGemmStage`` declares the whole sub-stack as one schedulable
-op whose operands are the stacked ``blocks`` arrays ([L, k, n] per
+**Layer-stacked templates (scan-over-layers).** Every builder emits ONE
+scanned layer body per homogeneous sub-stack of layers: the params tree
+already stores weights stacked along a leading layer axis, so a
+``StackedGemmStage`` declares the whole sub-stack as one schedulable op
+whose operands are the stacked ``blocks`` arrays ([L, k, n] per
 projection, [L, E, k, n] for MoE expert packs) and whose execution is a
 jitted ``jax.lax.scan`` over the layer axis — template build, trace size
-and plan/weight-cache entries become O(1) in depth. Design points:
+and plan/weight-cache entries are O(1) in depth. Design points:
 
   * weight-key schema (``clustering.weight_key`` is the single
     constructor): stacked operands drop the layer index —
-    ``(model, pid, "stack", lo, hi, name[, expert])`` names ONE stacked
+    ``(model, pid, "stack", lo, hi, name)`` names ONE stacked
     operand covering layers [lo, hi), so the dispatch executor caches
     O(#operands) packed entries per tenant instead of O(#operands · L);
   * sub-stack partitioning (``partition_layers``): non-homogeneous stacks
@@ -82,27 +83,22 @@ and plan/weight-cache entries become O(1) in depth. Design points:
     per-layer xs are the norm scales, the layer's KV (or conv/h) cache
     slices and the padded stacked weights; ys stack the per-layer cache
     updates, which the epilogue concatenates back into the tenant's cache
-    — the same [L, ...] layout the per-layer path's ``jnp.stack`` built;
-  * the scan body's GEMMs (``_scan_gemm``) replicate the dispatch
-    executor's solo-dispatch bucketing EXACTLY at the tile they are given
-    (same m-tile bucket, same bk; the stacked operand's envelope is only
-    aligned to 512, ``dispatch.stacked_envelope``, which drops zero K
-    blocks and padding columns), which is what makes the stacked path
-    bit-identical to per-layer emission
-    (tests/test_stacked_templates.py asserts logits AND cache identity
-    for dense decode/prefill, MoE and SSM);
+    in its [L, ...] layout;
+  * the scan body's GEMMs (``_scan_gemm``) run the coalesced-GEMM kernel
+    at the body's own tile (``decode_tile`` / ``prefill_tile``) over one
+    xs slice of a cached stacked operand, whose envelope is aligned to
+    512 (``dispatch.stacked_envelope``);
   * cost/coalescing granularity: a stacked op is charged as L sequential
     tile-waves (``GemmShape.layers``), clusters on its full stack
     signature (``clustering.coalesce_key``) so only same-depth-and-dims
     tenants coalesce entire stacks, and carries the dominant operand's
     shape for EDF/aspect bookkeeping.
 
-``stacked_layers=False`` (builders + ServingEngine) keeps the per-layer
-emission path alive as the bit-identity oracle.
-
-Correctness: running a program must produce bit-comparable results to the
-monolithic ``Model.decode_step`` (tests/test_jit_engine.py), regardless of
-admission timing (tests/test_event_loop.py).
+Correctness: a program's logits and cache match the plain reference,
+``Model.decode_step`` / ``Model.prefill``, within the float32 tolerance
+the kernel's blocked K accumulation allows (tests/test_stacked_templates.py,
+tests/test_nondense_programs.py), regardless of admission timing
+(tests/test_event_loop.py).
 """
 from __future__ import annotations
 
@@ -205,7 +201,7 @@ class StackedOperand:
     the operand's sequential tile-waves — Lsub for dense operands,
     Lsub·E for expert packs (each scan step runs E expert GEMMs)."""
 
-    tag: str                       # per-layer stage tag, e.g. "ffn_gate"
+    tag: str                       # operand tag, e.g. "ffn_gate"
     weight_key: Tuple              # clustering.weight_key(..., stack=...)
     shape: GemmShape               # per-wave (m, n, k) with layers = waves
     # lazy builder of the raw stacked array (a [lo:hi) view of the params
@@ -220,12 +216,10 @@ class StackedOperand:
 @dataclasses.dataclass
 class StackedGemmStage:
     """One scanned layer body: a whole homogeneous sub-stack of layers as
-    a single schedulable op (the stacked-template analogue of ~6·Lsub
-    ``GemmStage``s). The session fetches each operand's padded stack from
-    the executor's persistent cache (``SuperkernelExecutor.
-    stacked_operand``) and calls ``run`` — a jitted ``jax.lax.scan`` whose
-    body replays the per-layer math with ``_scan_gemm`` standing in for
-    the executor's solo dispatch, bit-identically."""
+    a single schedulable op. The session fetches each operand's padded
+    stack from the executor's persistent cache (``SuperkernelExecutor.
+    stacked_operand``) and calls ``run`` — a jitted ``jax.lax.scan`` over
+    the layers whose GEMMs are ``_scan_gemm`` calls."""
 
     tag: str                       # body identity, e.g. "body_0_12"
     weight_key: Tuple              # clustering.weight_key("body", stack=...)
@@ -252,14 +246,11 @@ _PROG_UIDS = itertools.count(1)
 
 def _scan_gemm(a: jax.Array, w_pad: jax.Array, n_real: int, *, bm: int,
                bn: int, bk: int, interpret: bool) -> jax.Array:
-    """One GEMM inside a scanned layer body, replicating the dispatch
-    executor's solo dispatch EXACTLY at the tile it is given — same m-tile
-    bucket, same block clamping, with ``w_pad`` one xs slice of a cached
-    ``stacked_operand`` — so a stacked body is bit-identical to the
-    per-layer path dispatching each stage at that tile. Every output
-    element sums its K blocks in bk order, so tiles that share bk give the
-    same bits, and the stacked operand's smaller aligned envelope drops
-    only zero blocks of K and padding columns of N."""
+    """One GEMM inside a scanned layer body at the tile it is given: ``a``
+    is padded to whole m-tiles and to the envelope's K, ``w_pad`` is one
+    xs slice of a cached ``stacked_operand``, and the blocks along N and K
+    shrink to divisors of the envelope (``_panel``). Returns the real
+    ``[m, n_real]`` rows and columns."""
     m = int(a.shape[0])
     K, N = int(w_pad.shape[-2]), int(w_pad.shape[-1])
     m_tiles = _tile_bucket([m], bm)
@@ -487,8 +478,7 @@ class ProgramTemplate:
                              _suffix_fn=self.gemm_suffix)
 
 
-def dense_program_cache_key(model, params, batch: int, cache, *,
-                            stacked: bool = True) -> Tuple:
+def dense_program_cache_key(model, params, batch: int, cache) -> Tuple:
     """Plan-cache key for a dense decode template: (model identity, active
     batch m, dtype, cache geometry). Params identity is deliberately NOT in
     the key — a weight hot-swap lands on the same slot and is caught by the
@@ -497,116 +487,17 @@ def dense_program_cache_key(model, params, batch: int, cache, *,
     closures. The guard also pins both objects, so ``id(model)`` here can
     never be a recycled address aliasing a dead model.
 
-    The emission regime and depth are part of the key: a stacked and a
-    per-layer template of the same model must never alias, and stacked
-    geometry (sub-stack spans) is a function of num_layers."""
+    The depth is part of the key: the sub-stack spans are a function of
+    num_layers."""
     kc = cache["layers"]["k"]
     return ("dense-decode", model.cfg.name, id(model), batch,
             str(params["embed"].dtype), str(kc.dtype), tuple(kc.shape),
-            ("stacked", bool(stacked), model.cfg.num_layers))
+            model.cfg.num_layers)
 
 
 # ---------------------------------------------------------------------------
 # program builders for dense GQA (the real-execution demo family)
 # ---------------------------------------------------------------------------
-
-def _emit_dense_body(cfg: ModelConfig, params, stages: List[Stage], *,
-                     m_rows: int, attend_for, ffn_for=None,
-                     attend_reads: Tuple = ("wq", "wk", "wv", "cache")
-                     ) -> None:
-    """Emit the per-layer stage scaffolding shared by the dense DECODE and
-    PREFILL builders: pre-norm, the wq/wk/wv projections, the phase-specific
-    attention glue (``attend_for(l, lp, is_global)``), wo, post-norm and the
-    gated FFN. There is deliberately exactly ONE copy of this: cross-phase
-    operand sharing (a prefill op loading weights once with a decode op)
-    requires both builders to emit byte-identical weight keys and tags, so
-    the scaffolding must never drift between them.
-
-    ``m_rows`` is the activation-row count of every GEMM stage — the slotted
-    batch for decode, the padded prompt length for prefill.
-
-    ``ffn_for(l, lp, stages)``, when given, replaces the dense gated-FFN
-    emission for layer ``l`` (the MoE builder supplies the router glue +
-    per-expert GemmStages); it consumes ``env['h2']`` (set by the post-attn
-    glue) and must leave ``env['x']`` updated with the FFN residual. The
-    attention scaffolding — weight keys and tags included — stays the
-    shared copy, so MoE attention GEMMs coalesce with dense tenants'."""
-    hd = cfg.resolved_head_dim
-    blocks = params["blocks"]
-    # weight identity includes the params object: two tenants of the same
-    # architecture only share operands (and thus a single weight load in
-    # the superkernel) when they literally serve the same weights
-    pid = id(params)
-
-    def glue(fn, reads=None, writes=None):
-        stages.append(GlueStage(fn, reads=reads, writes=writes))
-
-    def gemm(tag, wkey, wfn, infn, outfn, n, k, reads, writes):
-        stages.append(GemmStage(tag, wkey, wfn, infn, outfn,
-                                shape=GemmShape(m=m_rows, n=n, k=k),
-                                reads=reads, writes=writes))
-
-    for l in range(cfg.num_layers):
-        lp = jax.tree_util.tree_map(lambda a, l=l: a[l], blocks)
-        is_global = cfg.layer_is_global(l)
-
-        def pre_attn(env, lp=lp):
-            env["h"] = rmsnorm(env["x"], lp["ln1"], cfg.norm_eps)
-
-        glue(pre_attn, reads=("x",), writes=("h",))
-        for name, n_heads in (("wq", cfg.num_heads), ("wk", cfg.num_kv_heads),
-                              ("wv", cfg.num_kv_heads)):
-            gemm(f"attn_{name}", weight_key(cfg.name, pid, name, layer=l),
-                 lambda lp=lp, name=name: lp["attn"][name],
-                 lambda env: env["h"],
-                 lambda env, out, name=name: env.__setitem__(name, out),
-                 n_heads * hd, cfg.d_model, ("h",), (name,))
-
-        # the attention glue's read set is phase-specific (decode streams
-        # the slotted cache, prefill ropes by env positions) — the caller
-        # passes the accurate set via attend_reads
-        glue(attend_for(l, lp, is_global), reads=attend_reads,
-             writes=("attn_out", "new_layers"))
-        gemm("attn_wo", weight_key(cfg.name, pid, "wo", layer=l),
-             lambda lp=lp: lp["attn"]["wo"],
-             lambda env: env["attn_out"],
-             lambda env, out: env.__setitem__("attn_proj", out),
-             cfg.d_model, cfg.num_heads * hd, ("attn_out",), ("attn_proj",))
-
-        def post_attn(env, lp=lp):
-            env["x"] = env["x"] + env["attn_proj"]
-            env["h2"] = rmsnorm(env["x"], lp["ln2"], cfg.norm_eps)
-
-        glue(post_attn, reads=("x", "attn_proj"), writes=("x", "h2"))
-        if ffn_for is not None:
-            ffn_for(l, lp, stages)
-            continue
-        gemm("ffn_gate", weight_key(cfg.name, pid, "w_gate", layer=l),
-             lambda lp=lp: lp["mlp"]["w_gate"],
-             lambda env: env["h2"],
-             lambda env, out: env.__setitem__("gate", out),
-             cfg.d_ff, cfg.d_model, ("h2",), ("gate",))
-        gemm("ffn_up", weight_key(cfg.name, pid, "w_up", layer=l),
-             lambda lp=lp: lp["mlp"]["w_up"],
-             lambda env: env["h2"],
-             lambda env, out: env.__setitem__("up", out),
-             cfg.d_ff, cfg.d_model, ("h2",), ("up",))
-
-        def act(env):
-            env["act"] = _silu_mul(env["gate"], env["up"])
-
-        glue(act, reads=("gate", "up"), writes=("act",))
-        gemm("ffn_down", weight_key(cfg.name, pid, "w_down", layer=l),
-             lambda lp=lp: lp["mlp"]["w_down"],
-             lambda env: env["act"],
-             lambda env, out: env.__setitem__("down", out),
-             cfg.d_model, cfg.d_ff, ("act",), ("down",))
-
-        def post_ffn(env):
-            env["x"] = env["x"] + env["down"]
-
-        glue(post_ffn, reads=("x", "down"), writes=("x",))
-
 
 # tied-embedding transposes, memoized per embed-array identity: every
 # template of one (model, params) — decode at any batch size, prefill at
@@ -639,9 +530,9 @@ def _tied_unembed(params) -> jax.Array:
 
 
 def _emit_decode_embed(cfg: ModelConfig, params, stages: List[Stage]) -> None:
-    """Token-embedding prologue shared by every DECODE builder (dense/MoE
-    via the GQA scaffold, SSM): scaled embed of the step's [B, 1] tokens
-    squeezed to [B, d], plus the cache-position snapshot."""
+    """Token-embedding prologue shared by every DECODE builder: scaled
+    embed of the step's [B, 1] tokens squeezed to [B, d], plus the
+    cache-position snapshot."""
 
     def embed(env):
         x = params["embed"][env["tokens"]]
@@ -654,7 +545,7 @@ def _emit_decode_embed(cfg: ModelConfig, params, stages: List[Stage]) -> None:
 
 def _emit_final_logits(cfg: ModelConfig, params, stages: List[Stage], *,
                        m_rows: int) -> None:
-    """Final-norm + unembed tail shared by every decode builder."""
+    """Final-norm + unembed tail of the GQA and SSM decode builders."""
 
     def final_norm(env):
         env["hf"] = rmsnorm(env["x"], params["final_norm"], cfg.norm_eps)
@@ -666,7 +557,7 @@ def _emit_final_logits(cfg: ModelConfig, params, stages: List[Stage], *,
 def _emit_unembed(cfg: ModelConfig, params, stages: List[Stage], *,
                   m_rows: int) -> None:
     """Emit the unembedding GEMM over ``env['hf']`` into ``env['logits']``
-    (shared by both builders; ``m_rows`` = the normed rows to unembed)."""
+    (decode and prefill; ``m_rows`` = the normed rows to unembed)."""
     pid = id(params)
     if cfg.tie_embeddings:
         # hoisted to template-build time AND shared across templates (see
@@ -688,11 +579,10 @@ def _emit_unembed(cfg: ModelConfig, params, stages: List[Stage], *,
 def _gqa_decode_attend(cfg: ModelConfig, B: int, q_flat, k_flat, v_flat,
                        kc, vc, pos, is_global: bool, out_dtype
                        ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """One layer of single-token slotted-cache GQA attention: the PURE math
-    shared verbatim by the per-layer glue (``_decode_attend_for``) and the
-    stacked scan body — one copy so the two paths cannot drift. ``kc``/
-    ``vc`` are the layer's cache slices [B, Hkv, S, hd]; returns
-    (attn_out [B, H·hd], new kc, new vc)."""
+    """One layer of single-token slotted-cache GQA attention, the pure
+    math the dense, MoE and hybrid decode bodies share. ``kc``/``vc`` are
+    the layer's cache slices [B, Hkv, S, hd]; returns (attn_out [B, H·hd],
+    new kc, new vc)."""
     hd = cfg.resolved_head_dim
     q = q_flat.reshape(B, 1, cfg.num_heads, hd)
     k = k_flat.reshape(B, 1, cfg.num_kv_heads, hd)
@@ -720,139 +610,13 @@ def _gqa_decode_attend(cfg: ModelConfig, B: int, q_flat, k_flat, v_flat,
     return (o.reshape(B, cfg.num_heads * hd).astype(out_dtype), kc, vc)
 
 
-# ---------------------------------------------------------------------------
-# jitted per-layer glue — the bit-identity bridge to the stacked regime
-# ---------------------------------------------------------------------------
-# XLA CPU contracts mul→add chains into FMAs (and loop-fuses
-# transcendentals) when compiling a jitted program, but not when executing
-# the same ops eagerly one by one — so per-layer glue running eager math
-# computes different last-ulp bits than the SAME helper inlined in a jitted
-# scan body. Standalone-jitting a helper is bitwise identical to inlining
-# it in a jitted scan (measured on this backend), so the per-layer (oracle)
-# glue calls these memoized jit wrappers instead of the raw helpers: both
-# template regimes then execute jit-compiled bits and the
-# stacked-vs-per-layer contract is exact token/cache equality.
-# ModelConfig/SSMConfig/MoEConfig are frozen dataclasses, so configs key
-# the memo by VALUE — two tenants of the same architecture share entries.
-_GLUE_JITS: Dict[Tuple, Callable] = {}
-
-# silu(gate) ⊙ up — the gated-FFN activation glue (dense layers and MoE
-# per-expert stages). jax.jit traces lazily per (shape, dtype).
-_silu_mul = jax.jit(lambda gate, up: jax.nn.silu(gate) * up)
-
-
-def _jitted_decode_attend(cfg: ModelConfig, B: int, is_global: bool,
-                          out_dtype) -> Callable:
-    key = ("decode-attend", cfg, B, bool(is_global),
-           jnp.dtype(out_dtype).name)
-    fn = _GLUE_JITS.get(key)
-    if fn is None:
-        def decode_attend(q, k, v, kc, vc, pos):
-            return _gqa_decode_attend(cfg, B, q, k, v, kc, vc, pos,
-                                      is_global, out_dtype)
-
-        fn = _GLUE_JITS[key] = jax.jit(decode_attend)
-    return fn
-
-
-def _jitted_prefill_attend(cfg: ModelConfig, Sp: int, is_global: bool,
-                           out_dtype) -> Callable:
-    key = ("prefill-attend", cfg, Sp, bool(is_global),
-           jnp.dtype(out_dtype).name)
-    fn = _GLUE_JITS.get(key)
-    if fn is None:
-        def prefill_attend(q, k, v, positions):
-            return _causal_prefill_attend(cfg, Sp, q, k, v, positions,
-                                          is_global, out_dtype)
-
-        fn = _GLUE_JITS[key] = jax.jit(prefill_attend)
-    return fn
-
-
-def _jitted_moe_route(cfg: ModelConfig, B: int, C: int) -> Callable:
-    from repro.models import moe as moe_lib
-    mcfg = cfg.moe
-    key = ("moe-route", cfg, B, C)
-    fn = _GLUE_JITS.get(key)
-    if fn is None:
-        E, top_k, d = mcfg.num_experts, mcfg.top_k, cfg.d_model
-
-        def moe_route_dispatch(router_p, h2):
-            weights, experts, _aux = moe_lib.route(router_p, h2, mcfg)
-            xg = h2.reshape(1, B, d)
-            wgt = weights.reshape(1, B, top_k)
-            eg = experts.reshape(1, B, top_k)
-            buf, meta = jax.vmap(
-                lambda xx, ww, ee: moe_lib.dispatch_tokens(
-                    xx, ww, ee, E, top_k, C))(xg, wgt, eg)
-            return buf, meta, wgt
-
-        fn = _GLUE_JITS[key] = jax.jit(moe_route_dispatch)
-    return fn
-
-
-def _jitted_moe_combine(cfg: ModelConfig, B: int) -> Callable:
-    from repro.models import moe as moe_lib
-    key = ("moe-combine", cfg, B)
-    fn = _GLUE_JITS.get(key)
-    if fn is None:
-        d = cfg.d_model
-
-        def moe_combine(out_buf, wgt, meta):
-            return jax.vmap(
-                lambda ob, ww, mm: moe_lib.combine_tokens(
-                    ob, ww.reshape(-1), mm, B, d))(out_buf, wgt, meta)
-
-        fn = _GLUE_JITS[key] = jax.jit(moe_combine)
-    return fn
-
-
-def _jitted_ssm_core(cfg: ModelConfig) -> Callable:
-    from repro.models import ssm as ssm_lib
-    key = ("ssm-core", cfg)
-    fn = _GLUE_JITS.get(key)
-    if fn is None:
-        scfg, d = cfg.ssm, cfg.d_model
-
-        def ssm_decode_core(mamba_p, zxbcdt, conv, h):
-            return ssm_lib.decode_core(mamba_p, zxbcdt,
-                                       {"conv": conv, "h": h}, scfg, d)
-
-        fn = _GLUE_JITS[key] = jax.jit(ssm_decode_core)
-    return fn
-
-
-def _decode_attend_for(cfg: ModelConfig, B: int):
-    """Single-token slotted-cache attention glue factory, shared by the
-    dense and MoE decode builders (MoE layers keep standard GQA attention,
-    so both families must stay byte-identical here)."""
-
-    def attend_for(l, lp, is_global):
-        # one new token per row against the slotted cache, per-row positions
-        def attend(env, l=l, is_global=is_global):
-            cache = env["cache"]
-            pos = jnp.broadcast_to(jnp.asarray(cache["pos"]), (B,))
-            attn_out, kc, vc = _jitted_decode_attend(
-                cfg, B, is_global, env["h"].dtype)(
-                env["wq"], env["wk"], env["wv"],
-                cache["layers"]["k"][l], cache["layers"]["v"][l], pos)
-            env["new_layers"]["k"].append(kc)
-            env["new_layers"]["v"].append(vc)
-            env["attn_out"] = attn_out
-
-        return attend
-
-    return attend_for
-
-
 def _stacked_dense_body_stage(model, params, B: int, lo: int, hi: int, *,
                               moe: bool = False) -> StackedGemmStage:
-    """ONE scanned decode body covering layers [lo, hi) of a GQA model —
-    the stacked replacement for ~6·Lsub (dense) or (4+3·E)·Lsub (MoE)
-    per-layer stages. The scan body replays the per-layer math exactly:
-    ``_scan_gemm`` for every projection (replicating the executor's solo
-    dispatch), ``_gqa_decode_attend`` for attention, and the literal
-    ``moe_lib`` route/dispatch/combine calls for the MoE FFN."""
+    """ONE scanned decode body covering layers [lo, hi) of a GQA model:
+    ``_scan_gemm`` for every projection, ``_gqa_decode_attend`` for
+    attention, and for MoE the literal ``moe_lib`` route/dispatch/combine
+    calls ``moe_ffn`` makes (so capacity and drop semantics cannot drift)
+    around E per-expert GEMMs over the [C, d] expert buffer."""
     cfg: ModelConfig = model.cfg
     hd = cfg.resolved_head_dim
     d = cfg.d_model
@@ -912,10 +676,9 @@ def _stacked_dense_body_stage(model, params, B: int, lo: int, hi: int, *,
                               interpret=interpret)
 
         # every per-layer param enters as a jit ARGUMENT (via xs), never a
-        # closure: XLA codegens array CONSTANTS differently than traced
-        # arguments in the last ulp (measured on decode_core's einsum
-        # chain), and the per-layer oracle's jitted glue receives the same
-        # arrays as arguments — bit-identity requires matching regimes
+        # closure: XLA would bake a closed-over array in as a constant,
+        # pinning its buffer and codegenning it differently in the last
+        # ulp (analysis/lint.py TH001)
         def scan_fn(x, pos_in, kc_full, vc_full, w, aux):
             pos = jnp.broadcast_to(pos_in, (B,))
 
@@ -993,12 +756,12 @@ def _stacked_dense_body_stage(model, params, B: int, lo: int, hi: int, *,
         reads=("x", "cache"), writes=("x", "new_layers"))
 
 
-def _build_stacked_gqa_decode_template(model, params, batch: int, *,
-                                       moe: bool = False) -> ProgramTemplate:
-    """Stacked counterpart of ``_build_gqa_decode_template``: one scanned
-    body stage per homogeneous sub-stack instead of per-layer emission.
-    The epilogue concatenates the bodies' [Lsub, ...] cache updates —
-    the same [L, ...] layout the per-layer path's ``jnp.stack`` built."""
+def _build_gqa_decode_template(model, params, batch: int, *,
+                               moe: bool = False) -> ProgramTemplate:
+    """Decode template of every GQA-attention family (dense, and MoE with
+    ``moe``): embed glue, one scanned body stage per homogeneous sub-stack,
+    final norm and unembed, and an epilogue that concatenates the bodies'
+    [Lsub, ...] cache updates into the tenant's [L, ...] KV cache."""
     cfg: ModelConfig = model.cfg
     stages: List[Stage] = []
     _emit_decode_embed(cfg, params, stages)
@@ -1022,36 +785,6 @@ def _build_stacked_gqa_decode_template(model, params, batch: int, *,
     return ProgramTemplate(stages=stages, batch=batch, model_name=cfg.name)
 
 
-def _build_gqa_decode_template(model, params, batch: int, *,
-                               ffn_for=None) -> ProgramTemplate:
-    """Shared decode-template scaffold for every GQA-attention family:
-    embed glue, the per-layer attention + FFN body (``ffn_for`` swaps the
-    dense gated FFN for a family-specific emitter — MoE), final norm,
-    unembed and the KV-cache write-back epilogue."""
-    cfg: ModelConfig = model.cfg
-    B = batch
-    stages: List[Stage] = []
-
-    _emit_decode_embed(cfg, params, stages)
-    _emit_dense_body(cfg, params, stages, m_rows=B,
-                     attend_for=_decode_attend_for(cfg, B), ffn_for=ffn_for)
-    _emit_final_logits(cfg, params, stages, m_rows=B)
-
-    def finish(env):
-        cache = env["cache"]
-        env["cache"] = {
-            "pos": cache["pos"] + 1,
-            "layers": {
-                "k": jnp.stack(env["new_layers"]["k"]),
-                "v": jnp.stack(env["new_layers"]["v"]),
-            },
-        }
-
-    stages.append(GlueStage(finish, reads=("cache", "new_layers"),
-                            writes=("cache",)))
-    return ProgramTemplate(stages=stages, batch=B, model_name=cfg.name)
-
-
 def _plain_blocks(cfg: ModelConfig) -> None:
     """The GQA + FFN templates serve the embedding and head multipliers
     only: a config whose blocks scale their own paths (keys, attention's
@@ -1060,23 +793,18 @@ def _plain_blocks(cfg: ModelConfig) -> None:
         == Multipliers(), f"{cfg.name}: block multipliers unsupported here"
 
 
-def build_dense_decode_template(model, params, batch: int, *,
-                                stacked: bool = True) -> ProgramTemplate:
+def build_dense_decode_template(model, params, batch: int
+                                ) -> ProgramTemplate:
     """Compile the decode step of a dense GQA model into a ProgramTemplate.
 
     Equivalent to ``Model.decode_step`` but with every projection GEMM
-    declared to the JIT. Supported: arch_type 'dense' (and the text path of
-    'vlm'). Per-step inputs (tokens [B, 1], KV cache) are read from the
-    bound program's env, so one template serves every steady-state step.
-
-    ``stacked=True`` (default) emits one scanned body per homogeneous
-    layer sub-stack — O(1)-in-depth build; ``stacked=False`` keeps the
-    per-layer emission (the bit-identity oracle).
+    declared to the JIT, in one scanned body per homogeneous layer
+    sub-stack. Supported: arch_type 'dense' (and the text path of 'vlm').
+    Per-step inputs (tokens [B, 1], KV cache) are read from the bound
+    program's env, so one template serves every steady-state step.
     """
     assert model.cfg.arch_type in ("dense", "vlm"), model.cfg.arch_type
     _plain_blocks(model.cfg)
-    if stacked:
-        return _build_stacked_gqa_decode_template(model, params, batch)
     return _build_gqa_decode_template(model, params, batch)
 
 
@@ -1084,8 +812,7 @@ def build_dense_decode_template(model, params, batch: int, *,
 # non-dense decode programs: MoE and SSM tenants as first-class streams
 # ---------------------------------------------------------------------------
 
-def moe_program_cache_key(model, params, batch: int, cache, *,
-                          stacked: bool = True) -> Tuple:
+def moe_program_cache_key(model, params, batch: int, cache) -> Tuple:
     """Plan-cache key for an MoE decode template. Same discipline as
     ``dense_program_cache_key`` (params identity lives in the lookup-site
     guard, not the key); the expert capacity C is a pure function of
@@ -1093,139 +820,49 @@ def moe_program_cache_key(model, params, batch: int, cache, *,
     kc = cache["layers"]["k"]
     return ("moe-decode", model.cfg.name, id(model), batch,
             str(params["embed"].dtype), str(kc.dtype), tuple(kc.shape),
-            ("stacked", bool(stacked), model.cfg.num_layers))
+            model.cfg.num_layers)
 
 
-def build_moe_decode_template(model, params, batch: int, *,
-                              stacked: bool = True) -> ProgramTemplate:
+def build_moe_decode_template(model, params, batch: int
+                              ) -> ProgramTemplate:
     """Compile the decode step of an MoE model into a ProgramTemplate.
 
-    ``stacked=True`` (default) emits one scanned body per homogeneous
-    sub-stack — the router/dispatch/combine glue runs INSIDE the scan body
-    and the 3 expert packs become [Lsub, E, k, n] stacked operands;
-    ``stacked=False`` keeps the per-layer 3·E-GemmStage emission below
-    (the bit-identity oracle).
-
-    Equivalent to ``Model.decode_step`` for arch_type 'moe': the attention
-    scaffolding is the SAME emission as the dense builder (so MoE attention
-    GEMMs coalesce with dense tenants'), while each layer's FFN becomes
-
-      * a glue stage running the router + sort-based capacity dispatch
-        (``moe_lib.route`` / ``dispatch_tokens`` — literally the code
-        ``moe_ffn`` runs, so capacity/drop semantics cannot drift), then
-      * 3·E declared per-expert ``GemmStage``s (gate/up/down over the
-        [C, d] expert buffer) tagged ``expert_*`` with the expert index in
-        the weight key — so the same expert's GEMMs share operands across
-        tenants serving the same params, and coalesce with any tenant's
-        GEMMs sharing their (n, k) (a dense FFN with the same d_ff does),
-      * a combine glue scattering the weighted expert outputs back.
-
-    Expert weight slices are materialized ONCE here at build time
-    (``moe_lib.expert_ffn_weights``) and closed over, giving the dispatch
-    executor's packed-weight cache stable array identities — a fresh slice
-    per step would read as a phantom hot-swap and repack every tick.
-
-    Within one program the expert GEMMs execute in program order (one live
-    op per stream); the cross-tenant coalescing is the point
-    (``JitStats.expert_coalesced``).
+    Equivalent to ``Model.decode_step`` for arch_type 'moe'. The attention
+    is the dense body's (``_stacked_dense_body_stage``), so MoE attention
+    operands carry the same tags and shapes as a dense tenant's. Each
+    layer's FFN runs inside the scanned body: the router and sort-based
+    capacity dispatch, E per-expert gate/up/down GEMMs over the [C, d]
+    expert buffer, and the weighted combine. The three expert packs are
+    [Lsub, E, k, n] stacked operands tagged ``expert_*``, so a body holding
+    them counts in ``JitStats.expert_coalesced`` when it coalesces.
     """
     cfg: ModelConfig = model.cfg
     assert cfg.arch_type == "moe" and cfg.has_moe, cfg.arch_type
     _plain_blocks(cfg)
-    if stacked:
-        return _build_stacked_gqa_decode_template(model, params, batch,
-                                                  moe=True)
-    from repro.models import moe as moe_lib
-    mcfg = cfg.moe
-    B, d = batch, cfg.d_model
-    E, top_k = mcfg.num_experts, mcfg.top_k
-    # decode routes the step's B tokens as one group (moe_ffn's G=1 path)
-    C = moe_lib.capacity(B, mcfg)
-    pid = id(params)
-
-    def ffn_for(l, lp, stages):
-        moe_p = lp["moe"]
-        sliced = [moe_lib.expert_ffn_weights(moe_p, e) for e in range(E)]
-
-        def glue(fn, reads=None, writes=None):
-            stages.append(GlueStage(fn, reads=reads, writes=writes))
-
-        def route_dispatch(env, moe_p=moe_p):
-            buf, meta, wgt = _jitted_moe_route(cfg, B, C)(
-                moe_p["router"], env["h2"])
-            env["moe_buf"], env["moe_meta"] = buf, meta
-            env["moe_w"] = wgt
-            env["moe_down"] = [None] * E
-
-        glue(route_dispatch, reads=("h2",),
-             writes=("moe_buf", "moe_meta", "moe_w", "moe_down"))
-        for e in range(E):
-            wg, wu, wd = sliced[e]
-            stages.append(GemmStage(
-                "expert_gate",
-                weight_key(cfg.name, pid, "w_gate", layer=l, expert=e),
-                lambda w=wg: w,
-                lambda env, e=e: env["moe_buf"][0, e],
-                lambda env, out, e=e: env.__setitem__(("moe_gate", e), out),
-                shape=GemmShape(m=C, n=cfg.d_ff, k=d),
-                reads=("moe_buf",), writes=(("moe_gate", e),)))
-            stages.append(GemmStage(
-                "expert_up",
-                weight_key(cfg.name, pid, "w_up", layer=l, expert=e),
-                lambda w=wu: w,
-                lambda env, e=e: env["moe_buf"][0, e],
-                lambda env, out, e=e: env.__setitem__(("moe_up", e), out),
-                shape=GemmShape(m=C, n=cfg.d_ff, k=d),
-                reads=("moe_buf",), writes=(("moe_up", e),)))
-
-            def act(env, e=e):
-                env[("moe_act", e)] = _silu_mul(env.pop(("moe_gate", e)),
-                                                env.pop(("moe_up", e)))
-
-            glue(act, reads=(("moe_gate", e), ("moe_up", e)),
-                 writes=(("moe_act", e),))
-            stages.append(GemmStage(
-                "expert_down",
-                weight_key(cfg.name, pid, "w_down", layer=l, expert=e),
-                lambda w=wd: w,
-                lambda env, e=e: env[("moe_act", e)],
-                lambda env, out, e=e: env["moe_down"].__setitem__(e, out),
-                shape=GemmShape(m=C, n=d, k=cfg.d_ff),
-                reads=(("moe_act", e),), writes=("moe_down",)))
-
-        def combine(env):
-            out_buf = jnp.stack(env.pop("moe_down"), axis=0)[None]
-            y = _jitted_moe_combine(cfg, B)(out_buf, env.pop("moe_w"),
-                                            env.pop("moe_meta"))
-            env.pop("moe_buf")
-            env["x"] = env["x"] + y.reshape(B, d).astype(env["h2"].dtype)
-
-        glue(combine, reads=("moe_down", "moe_w", "moe_meta", "moe_buf",
-                             "x", "h2"),
-             writes=("x",))
-
-    return _build_gqa_decode_template(model, params, batch, ffn_for=ffn_for)
+    return _build_gqa_decode_template(model, params, batch, moe=True)
 
 
-def ssm_program_cache_key(model, params, batch: int, cache, *,
-                          stacked: bool = True) -> Tuple:
+def ssm_program_cache_key(model, params, batch: int, cache) -> Tuple:
     """Plan-cache key for an SSM decode template: (model identity, batch,
     dtype, recurrent-cache geometry). Guard discipline as for dense."""
     cc = cache["layers"]["conv"]
     return ("ssm-decode", model.cfg.name, id(model), batch,
             str(params["embed"].dtype), str(cc.dtype), tuple(cc.shape),
-            tuple(cache["layers"]["h"].shape),
-            ("stacked", bool(stacked), model.cfg.num_layers))
+            tuple(cache["layers"]["h"].shape), model.cfg.num_layers)
 
 
-def _build_stacked_ssm_decode_template(model, params, batch: int
-                                       ) -> ProgramTemplate:
-    """Stacked counterpart of the per-layer SSM builder: the whole
-    attention-free stack is ONE homogeneous sub-stack, so a single scanned
-    body stage declares the stacked in/out projections and runs the
-    selective-scan recurrence (``ssm_lib.decode_core`` — the same single
-    copy of the math) inside the scan body."""
+def build_ssm_decode_template(model, params, batch: int) -> ProgramTemplate:
+    """Compile the decode step of an attention-free SSM (Mamba-2/SSD) model
+    into a ProgramTemplate. Equivalent to ``Model.decode_step`` for
+    arch_type 'ssm'. The whole stack is ONE homogeneous sub-stack, so a
+    single scanned body stage declares the stacked in/out projections and
+    runs the selective-scan recurrence between them (``ssm_lib.
+    decode_core``, the function ``ssd_decode_step`` calls) inside the scan
+    body. The epilogue writes the conv windows and SSD states back into
+    the tenant's recurrent cache.
+    """
     cfg: ModelConfig = model.cfg
+    assert cfg.arch_type == "ssm" and cfg.has_ssm, cfg.arch_type
     from repro.models import ssm as ssm_lib
     scfg = cfg.ssm
     B, d = batch, cfg.d_model
@@ -1268,10 +905,8 @@ def _build_stacked_ssm_decode_template(model, params, batch: int
             return _scan_gemm(a, w, n, bm=bm, bn=bn, bk=bk,
                               interpret=interpret)
 
-        # per-layer params enter as jit ARGUMENTS (xs), not closures — XLA
-        # codegens embedded constants differently in the last ulp than
-        # traced arguments, which would break bit-identity with the
-        # per-layer oracle's jitted decode_core glue
+        # per-layer params enter as jit ARGUMENTS (xs), never closures
+        # (see _stacked_dense_body_stage)
         def ssm_decode_layers(x, conv_full, h_full, w, aux):
             def body(carry, per):
                 hh = rmsnorm(carry, per["ln1"], eps)
@@ -1323,104 +958,20 @@ def _build_stacked_ssm_decode_template(model, params, batch: int
     return ProgramTemplate(stages=stages, batch=B, model_name=cfg.name)
 
 
-def build_ssm_decode_template(model, params, batch: int, *,
-                              stacked: bool = True) -> ProgramTemplate:
-    """Compile the decode step of an attention-free SSM (Mamba-2/SSD) model
-    into a ProgramTemplate. Equivalent to ``Model.decode_step`` for
-    arch_type 'ssm': per layer, the in projection ([B, d] → z/xBC/dt) and
-    the out projection are declared ``GemmStage``s — coalescible across
-    tenants — while the selective-scan recurrence between them runs as glue
-    via ``ssm_lib.decode_core`` (the SAME function ``ssd_decode_step``
-    calls, so the recurrence math has exactly one copy). The epilogue
-    stacks the per-layer conv windows + SSD states back into the tenant's
-    recurrent cache.
-    """
-    cfg: ModelConfig = model.cfg
-    assert cfg.arch_type == "ssm" and cfg.has_ssm, cfg.arch_type
-    if stacked:
-        return _build_stacked_ssm_decode_template(model, params, batch)
-    from repro.models import ssm as ssm_lib
-    scfg = cfg.ssm
-    B, d = batch, cfg.d_model
-    d_inner = scfg.inner(d)
-    n_in = scfg.in_proj_dim(d)
-    blocks = params["blocks"]
-    pid = id(params)
-    stages: List[Stage] = []
-
-    def glue(fn, reads=None, writes=None):
-        stages.append(GlueStage(fn, reads=reads, writes=writes))
-
-    _emit_decode_embed(cfg, params, stages)
-
-    def reset_layers(env):
-        env["new_layers"] = {"conv": [], "h": []}
-
-    glue(reset_layers, reads=(), writes=("new_layers",))
-    for l in range(cfg.num_layers):
-        lp = jax.tree_util.tree_map(lambda a, l=l: a[l], blocks)
-
-        def pre(env, lp=lp):
-            env["h"] = rmsnorm(env["x"], lp["ln1"], cfg.norm_eps)
-
-        glue(pre, reads=("x",), writes=("h",))
-        stages.append(GemmStage(
-            "ssm_in_proj", weight_key(cfg.name, pid, "in_proj", layer=l),
-            lambda lp=lp: lp["mamba"]["in_proj"],
-            lambda env: env["h"],
-            lambda env, out: env.__setitem__("zxbcdt", out),
-            shape=GemmShape(m=B, n=n_in, k=d),
-            reads=("h",), writes=("zxbcdt",)))
-
-        def scan(env, lp=lp, l=l):
-            layers = env["cache"]["layers"]
-            y, new_c = _jitted_ssm_core(cfg)(
-                lp["mamba"], env.pop("zxbcdt"),
-                layers["conv"][l], layers["h"][l])
-            env["new_layers"]["conv"].append(new_c["conv"])
-            env["new_layers"]["h"].append(new_c["h"])
-            env["ssm_y"] = y
-
-        glue(scan, reads=("cache", "zxbcdt"),
-             writes=("new_layers", "ssm_y"))
-        stages.append(GemmStage(
-            "ssm_out_proj", weight_key(cfg.name, pid, "out_proj", layer=l),
-            lambda lp=lp: lp["mamba"]["out_proj"],
-            lambda env: env["ssm_y"],
-            lambda env, out: env.__setitem__("x", env["x"] + out),
-            shape=GemmShape(m=B, n=d, k=d_inner),
-            reads=("ssm_y", "x"), writes=("x",)))
-
-    _emit_final_logits(cfg, params, stages, m_rows=B)
-
-    def finish(env):
-        cache = env["cache"]
-        env["cache"] = {
-            "pos": cache["pos"] + 1,
-            "layers": {
-                "conv": jnp.stack(env["new_layers"]["conv"]),
-                "h": jnp.stack(env["new_layers"]["h"]),
-            },
-        }
-
-    glue(finish, reads=("cache", "new_layers"), writes=("cache",))
-    return ProgramTemplate(stages=stages, batch=B, model_name=cfg.name)
-
-
 # ---------------------------------------------------------------------------
 # hybrid decode programs: attention and SSM heads side by side in each layer
 # ---------------------------------------------------------------------------
 
 def hybrid_program_cache_key(model, params, batch: int, cache) -> Tuple:
     """Plan-cache key for a hybrid decode template: (model identity, batch,
-    dtype, the KV rows' and the recurrent state's geometry). Guard
-    discipline as for dense. The template is stacked only."""
+    dtype, the KV rows' and the recurrent state's geometry, depth). Guard
+    discipline as for dense."""
     lay = cache["layers"]
     return ("hybrid-decode", model.cfg.name, id(model), batch,
             str(params["embed"].dtype), str(lay["k"].dtype),
             tuple(lay["k"].shape), str(lay["conv"].dtype),
             tuple(lay["conv"].shape), tuple(lay["h"].shape),
-            ("stacked", True, model.cfg.num_layers))
+            model.cfg.num_layers)
 
 
 def _stacked_hybrid_body_stage(model, params, B: int, lo: int, hi: int
@@ -1574,8 +1125,7 @@ def build_hybrid_decode_template(model, params, batch: int
     (``partition_layers`` over the sliding-window flags, as the dense path
     does), the head, and an epilogue that writes the KV rows and the
     recurrent state back into the tenant's cache side by side. Equivalent
-    to ``Model.decode_step`` for arch_type 'hybrid'. There is no per-layer
-    emission: every regime builds this template."""
+    to ``Model.decode_step`` for arch_type 'hybrid'."""
     cfg: ModelConfig = model.cfg
     assert cfg.arch_type == "hybrid" and cfg.has_ssm, cfg.arch_type
     stages: List[Stage] = []
@@ -1661,8 +1211,8 @@ def decode_tile(m: int, bk: int) -> Tuple[int, int, int]:
 def _causal_prefill_attend(cfg: ModelConfig, Sp: int, q_flat, k_flat,
                            v_flat, positions, is_global: bool, out_dtype
                            ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """One layer of causal prompt attention: the PURE math shared verbatim
-    by the per-layer prefill glue and the stacked scan body. Returns
+    """One layer of causal prompt attention, the pure math of the prefill
+    body. Returns
     (attn_out [Sp, H·hd], k [1, Hkv, Sp, hd] rope'd, v [1, Hkv, Sp, hd]
     raw) — the k/v pair in decode-cache layout, exactly what
     transformer._project_kv emits for the analytic path."""
@@ -1690,11 +1240,10 @@ def _causal_prefill_attend(cfg: ModelConfig, Sp: int, q_flat, k_flat,
 
 def _stacked_prefill_body_stage(model, params, Sp: int, lo: int, hi: int
                                 ) -> StackedGemmStage:
-    """ONE scanned prefill body covering layers [lo, hi): the stacked
-    replacement for the per-layer prompt-pass stages. The scan body replays
-    ``_causal_prefill_attend`` verbatim and stacks each layer's [1, Hkv,
-    Sp, hd] KV pair into a [Lsub, Hkv, Sp, hd] ys chunk — the layout the
-    shared prefill epilogue already concatenates."""
+    """ONE scanned prefill body covering layers [lo, hi): the prompt pass
+    of the sub-stack, with ``_causal_prefill_attend`` for attention. Each
+    layer's [1, Hkv, Sp, hd] KV pair stacks into a [Lsub, Hkv, Sp, hd] ys
+    chunk, which the prefill epilogue concatenates."""
     cfg: ModelConfig = model.cfg
     hd = cfg.resolved_head_dim
     d = cfg.d_model
@@ -1771,8 +1320,7 @@ def _stacked_prefill_body_stage(model, params, Sp: int, lo: int, hi: int
         reads=("x", "positions"), writes=("x", "new_layers"))
 
 
-def prefill_program_cache_key(model, params, seq_len: int, cache, *,
-                              stacked: bool = True) -> Tuple:
+def prefill_program_cache_key(model, params, seq_len: int, cache) -> Tuple:
     """Plan-cache key for a dense prefill template: (model identity, padded
     prompt bucket, dtype, cache geometry). Same guard discipline as
     ``dense_program_cache_key`` — params identity is caught by the lookup
@@ -1780,18 +1328,19 @@ def prefill_program_cache_key(model, params, seq_len: int, cache, *,
     kc = cache["layers"]["k"]
     return ("dense-prefill", model.cfg.name, id(model), seq_len,
             str(params["embed"].dtype), str(kc.dtype), tuple(kc.shape),
-            ("stacked", bool(stacked), model.cfg.num_layers))
+            model.cfg.num_layers)
 
 
-def build_dense_prefill_template(model, params, seq_len: int, *,
-                                 stacked: bool = True) -> ProgramTemplate:
+def build_dense_prefill_template(model, params, seq_len: int
+                                 ) -> ProgramTemplate:
     """Compile the PROMPT pass of a dense GQA model into a ProgramTemplate.
 
     Every projection GEMM is declared to the JIT with m = ``seq_len`` (the
     padded prefill bucket) — tall problems that enter the live op pool and
     coalesce with decode GEMVs (and other tenants' prefill GEMMs) sharing
-    their (n, k) weight dims. Equivalent to ``Model.prefill`` for arch_type
-    'dense', last-position logits only.
+    their (n, k) weight dims, in one scanned body per homogeneous layer
+    sub-stack. Equivalent to ``Model.prefill`` for arch_type 'dense',
+    last-position logits only.
 
     Per-request env entries (bound via ``ProgramTemplate.bind``'s
     ``env_extra``):
@@ -1809,7 +1358,6 @@ def build_dense_prefill_template(model, params, seq_len: int, *,
     """
     cfg: ModelConfig = model.cfg
     assert cfg.arch_type == "dense", cfg.arch_type
-    hd = cfg.resolved_head_dim
     Sp = seq_len
     stages: List[Stage] = []
 
@@ -1823,28 +1371,8 @@ def build_dense_prefill_template(model, params, seq_len: int, *,
 
     glue(embed, reads=("tokens",), writes=("x", "positions"))
 
-    if stacked:
-        for lo, hi in partition_layers(cfg.global_layer_flags()):
-            stages.append(_stacked_prefill_body_stage(model, params, Sp,
-                                                      lo, hi))
-    else:
-        def attend_for(l, lp, is_global):
-            # causal self-attention over the whole (padded) prompt
-            def attend(env, is_global=is_global):
-                attn_out, k_t, v_t = _jitted_prefill_attend(
-                    cfg, Sp, is_global, env["h"].dtype)(
-                    env["wq"], env["wk"], env["wv"], env["positions"])
-                env["new_layers"]["k"].append(k_t)
-                env["new_layers"]["v"].append(v_t)
-                env["attn_out"] = attn_out
-
-            return attend
-
-        # prefill attention never touches the live cache: k/v come from
-        # the projections and rope by env positions, landing in new_layers
-        _emit_dense_body(cfg, params, stages, m_rows=Sp,
-                         attend_for=attend_for,
-                         attend_reads=("wq", "wk", "wv", "positions"))
+    for lo, hi in partition_layers(cfg.global_layer_flags()):
+        stages.append(_stacked_prefill_body_stage(model, params, Sp, lo, hi))
 
     def final_norm(env):
         # only the last REAL position is unembedded (Model.prefill returns
@@ -2166,13 +1694,13 @@ class JitSession:
         self._push_op(prog, st)
 
     def _expert_collective_s(self, stream_id: int, m: int, k: int,
-                             layers: int = 1, dtype_bytes: int = 2) -> float:
-        """All-to-all charge for one expert-FFN trio of a device-spanning
-        MoE stream: dispatch scatters the [m, k] expert activations to the
-        shards, combine gathers the outputs back — 2·m·k bytes round trip
-        per scanned layer. Charged ONCE per trio (on the gate GEMM) so a
-        gate/up/down triple is not triple-billed. Local streams
-        (span <= 1) pay nothing."""
+                             layers: int, dtype_bytes: int) -> float:
+        """All-to-all charge for the expert-FFN trios of a device-spanning
+        MoE stream's scanned body: dispatch scatters the [m, k] expert
+        activations to the shards, combine gathers the outputs back — 2·m·k
+        bytes round trip per scanned layer. Charged ONCE per trio (on the
+        gate operand) so a gate/up/down triple is not triple-billed. Local
+        streams (span <= 1) pay nothing."""
         span = self.stream_span.get(stream_id, 1)
         if span <= 1:
             return 0.0
@@ -2199,9 +1727,6 @@ class JitSession:
         op.payload = (a, w, st.weight_key)
         op.prog_uid = prog.uid
         op.device = self.device
-        if st.tag == "expert_gate":
-            op.collective_s = self._expert_collective_s(
-                prog.stream_id, op.shape.m, op.shape.k)
         # per-request identity: the scheduler accounts SLO demotions per
         # request id, not per (stream, deadline) of the batch anchor
         op.req_deadlines = prog.req_deadlines

@@ -74,8 +74,8 @@ def rope_frequencies(head_dim: int, theta: float) -> jax.Array:
     # computed on HOST (numpy) so the table is one literal constant: a
     # device-side ``theta ** x`` evaluates through the runtime pow kernel
     # eagerly but through XLA's constant folder under jit, and the two
-    # disagree in the last ulp — which would break bit-identity between
-    # eager per-layer glue and jitted scan-over-layers bodies
+    # disagree in the last ulp between eager calls and jitted
+    # scan-over-layers bodies
     half = head_dim // 2
     return jnp.asarray(
         (1.0 / (theta ** (np.arange(half, dtype=np.float64) / half))
@@ -86,7 +86,7 @@ def rope_frequencies(head_dim: int, theta: float) -> jax.Array:
 # itself must NOT be evaluated on device: XLA's standalone cos/sin kernels
 # and its fused-loop vectorized versions disagree in the last ulp, so the
 # same ``cos(pos * freq)`` computes different bits inside a jitted
-# scan-over-layers body than in eager per-layer glue. A host table + device
+# scan-over-layers body than in an eager call. A host table + device
 # gather is bit-exact in every execution regime. 8192 positions bounds every
 # cache/prefill geometry this repo serves (gather clips beyond it).
 _ROPE_TABLE_POSITIONS = 8192
@@ -115,10 +115,7 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     """x: [..., seq, heads, head_dim]; positions: broadcastable to [..., seq].
 
     cos/sin come from a host-precomputed per-position table, so the trig is
-    a bit-exact gather in every execution regime — part of the bit-identity
-    contract between the per-layer and scan-over-layers template regimes
-    (the remaining fma-contraction hazard in the rotation is handled by the
-    JIT running per-layer glue through ``jax.jit``, core/jit.py)."""
+    a bit-exact gather in every execution regime, eager or jitted."""
     head_dim = x.shape[-1]
     cos_t, sin_t = _rope_trig_tables(head_dim, theta)
     idx = positions.astype(jnp.int32)

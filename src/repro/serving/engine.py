@@ -447,7 +447,6 @@ class ServingEngine:
                  predict_arrivals: bool = False,
                  arrival_alpha: float = 0.2,
                  weight_budget_bytes: Optional[int] = None,
-                 stacked_layers: bool = True,
                  certify: bool = False,
                  num_devices: int = 1,
                  devices: Optional[DeviceSet] = None,
@@ -467,14 +466,6 @@ class ServingEngine:
         # trace stays on ``last_trace`` (mutation tests re-certify it).
         self.certify = certify
         self.last_trace = None
-        # stacked_layers=True (default) compiles tenants to layer-stacked
-        # templates (one scanned body per homogeneous sub-stack; build and
-        # trace size O(1) in depth). False keeps per-layer emission — the
-        # bit-identity oracle. The analytic charges below (_ops_time etc.)
-        # are regime-independent: the stacked cost model charges a stacked
-        # op as L sequential tile-waves, the same total the per-layer path
-        # accumulates stage by stage.
-        self.stacked_layers = stacked_layers
         # vliw mode compiles dense tenants' prompt passes to KernelPrograms
         # (prefill GEMMs enter the live op pool and coalesce across
         # tenants); declared_prefill=False keeps the analytic serialized
@@ -801,10 +792,8 @@ class ServingEngine:
         prompt = self.make_prompt(t, req, rng)
         padded = jnp.pad(prompt, ((0, 0), (0, bucket - s)))
         template = self.jit.plan_cache.get_or_build(
-            prefill_program_cache_key(t.model, t.params, bucket, t.cache,
-                                      stacked=self.stacked_layers),
-            lambda: build_dense_prefill_template(
-                t.model, t.params, bucket, stacked=self.stacked_layers),
+            prefill_program_cache_key(t.model, t.params, bucket, t.cache),
+            lambda: build_dense_prefill_template(t.model, t.params, bucket),
             guard=(t.model, t.params),
             group=("tenant-prefill", t.name, bucket))
         final = req.arrival_t + req.slo_s
@@ -889,30 +878,16 @@ class ServingEngine:
             min(finals) if finals else math.inf
         batch = int(t.slot_tok.shape[0])
         arch = t.cfg.arch_type
-        stacked = self.stacked_layers
-        if arch == "moe":
-            key = moe_program_cache_key(t.model, t.params, batch, t.cache,
-                                        stacked=stacked)
-            build = lambda: build_moe_decode_template(  # noqa: E731
-                t.model, t.params, batch, stacked=stacked)
-        elif arch == "ssm":
-            key = ssm_program_cache_key(t.model, t.params, batch, t.cache,
-                                        stacked=stacked)
-            build = lambda: build_ssm_decode_template(  # noqa: E731
-                t.model, t.params, batch, stacked=stacked)
-        elif arch == "hybrid":
-            # stacked in every regime: the hybrid template has no
-            # per-layer emission
-            key = hybrid_program_cache_key(t.model, t.params, batch, t.cache)
-            build = lambda: build_hybrid_decode_template(  # noqa: E731
-                t.model, t.params, batch)
-        else:
-            key = dense_program_cache_key(t.model, t.params, batch, t.cache,
-                                          stacked=stacked)
-            build = lambda: build_dense_decode_template(  # noqa: E731
-                t.model, t.params, batch, stacked=stacked)
+        key_fn, build_fn = {
+            "moe": (moe_program_cache_key, build_moe_decode_template),
+            "ssm": (ssm_program_cache_key, build_ssm_decode_template),
+            "hybrid": (hybrid_program_cache_key,
+                       build_hybrid_decode_template),
+        }.get(arch, (dense_program_cache_key, build_dense_decode_template))
         template = self.jit.plan_cache.get_or_build(
-            key, build, guard=(t.model, t.params), group=("tenant", t.name))
+            key_fn(t.model, t.params, batch, t.cache),
+            lambda: build_fn(t.model, t.params, batch),
+            guard=(t.model, t.params), group=("tenant", t.name))
         return template.bind(
             stream_id=stream_id, tokens=t.slot_tok, cache=t.cache,
             arrival_t=now, deadline_t=deadline,

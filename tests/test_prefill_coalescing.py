@@ -65,22 +65,26 @@ def test_group_ops_exact_merges_prefill_gemms_with_decode_gemvs():
 # the prefill program computes exactly what Model.prefill computes
 # ---------------------------------------------------------------------------
 
-def test_prefill_program_matches_model_prefill(rng):
+@pytest.mark.parametrize("s", [5, 12, 13, 100])
+def test_prefill_program_matches_model_prefill(s, rng):
     """A declared prefill program (padded to its bucket, run through real
     superkernel dispatches) reproduces Model.prefill's last-position logits
-    and writes exactly the KV slot rows the analytic admission writes."""
+    and writes exactly the KV slot rows the analytic admission writes.
+    Buckets 8, 16, 16 and 128: every length but 16 pads."""
     cfg = smoke_config("gemma3-1b")
     m = Model(cfg, param_dtype=jnp.float32)
     params = m.init(rng)
-    s = 13                                    # odd length: real padding
     prompt = jax.random.randint(jax.random.fold_in(rng, 7), (1, s), 0,
                                 cfg.vocab_size)
-    want_logits, pc = m.prefill(params, {"tokens": prompt}, cache_len=32)
+    cache_len = max(32, s)
+    want_logits, pc = m.prefill(params, {"tokens": prompt},
+                                cache_len=cache_len)
 
     bucket = prefill_bucket(s)
-    assert bucket == 16
+    if s == 13:
+        assert bucket == 16
     template = build_dense_prefill_template(m, params, bucket)
-    cache = m.init_cache(2, 32)
+    cache = m.init_cache(2, cache_len)
     padded = jnp.pad(prompt, ((0, 0), (0, bucket - s)))
     prog = template.bind(stream_id=0, tokens=padded, cache=cache,
                          env_extra={"real_len": s, "slot": 1, "req": None})

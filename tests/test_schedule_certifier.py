@@ -23,7 +23,11 @@ from repro.analysis import (ConservationHazard, DeadlineHazard, DepEdge,
                             check_conservation, cross_program_conflicts)
 from repro.configs import smoke_config
 from repro.core.jit import (JitStats, VLIWJit, build_dense_decode_program,
-                            build_dense_decode_template)
+                            build_dense_decode_template,
+                            build_dense_prefill_template,
+                            build_hybrid_decode_template,
+                            build_moe_decode_template,
+                            build_ssm_decode_template)
 from repro.models import Model
 from repro.serving import ServeRequest, ServingEngine, Tenant, two_wave_trace
 
@@ -366,13 +370,19 @@ def test_depgraph_flags_unsourced_reads():
     assert g.unsourced_reads == [(0, "bogus")]
 
 
-@pytest.mark.parametrize("stacked", [True, False])
-def test_dense_decode_template_fully_declared(dense_models, stacked):
-    """Every stage the dense builders emit declares its access sets (no
-    conservative wildcards) and every read has a source: an upstream
-    writer or a bind-time binding."""
-    m, p = dense_models["gemma3-1b"]
-    template = build_dense_decode_template(m, p, 2, stacked=stacked)
+@pytest.mark.parametrize("build,arch", [
+    (build_dense_decode_template, "gemma3-1b"),
+    (build_moe_decode_template, "grok-1-314b"),
+    (build_ssm_decode_template, "mamba2-2.7b"),
+    (build_hybrid_decode_template, "falcon-h1-34b"),
+    (build_dense_prefill_template, "gemma3-1b"),
+], ids=["dense", "moe", "ssm", "hybrid", "prefill"])
+def test_dense_decode_template_fully_declared(build, arch):
+    """Every stage the builders emit (the four decode families and dense
+    prefill) declares its access sets (no conservative wildcards) and
+    every read has a source: an upstream writer or a bind-time binding."""
+    m = Model(smoke_config(arch), param_dtype=jnp.float32)
+    template = build(m, m.init(jax.random.PRNGKey(1)), 2)
     g = build_depgraph(template)
     assert not g.conservative
     assert not g.unsourced_reads

@@ -1,11 +1,11 @@
-"""Layer-stacked KernelPrograms (ISSUE 6 tentpole): scan-over-layers
-templates must be BIT-identical to the per-layer oracle emission
-(``stacked=False``) — logits and cache leaves, not just tokens — across
-dense decode, dense prefill, MoE and SSM at several batch sizes; the
-stacked dispatch path must keep the steady-state plan-cache hit rate and
-the packed-weight guard discipline (zero phantom invalidations, real
-hot-swaps trip the guard); and a production-depth (48-layer) config must
-serve end-to-end through the vliw mode with O(1)-in-depth templates."""
+"""Layer-stacked KernelPrograms: scan-over-layers decode templates must
+match the plain reference, ``Model.decode_step`` — logits and every cache
+leaf — across dense, MoE and SSM at several batch sizes; a template holds
+one scanned body per homogeneous sub-stack; the stacked dispatch path must
+keep the steady-state plan-cache hit rate and the packed-weight guard
+discipline (zero phantom invalidations, real hot-swaps trip the guard);
+and a production-depth (48-layer) config must serve end-to-end through
+the vliw mode with O(1)-in-depth templates."""
 import dataclasses
 
 import jax
@@ -15,12 +15,11 @@ import pytest
 
 from repro.configs import smoke_config
 from repro.core.jit import (DECODE_BM_MAX, DECODE_BN, PREFILL_BM_MAX,
-                            PREFILL_BN, StackedGemmStage, VLIWJit,
+                            PREFILL_BN, GemmStage, StackedGemmStage, VLIWJit,
                             _panel, build_dense_decode_template,
-                            build_dense_prefill_template,
                             build_moe_decode_template,
                             build_ssm_decode_template, decode_tile,
-                            partition_layers, prefill_bucket, prefill_tile)
+                            partition_layers, prefill_tile)
 from repro.models import Model
 from repro.serving import ServeRequest, ServingEngine, Tenant
 
@@ -29,32 +28,19 @@ DECODE_BUILDERS = {
     "moe": build_moe_decode_template,
     "ssm": build_ssm_decode_template,
 }
-ARCHS = {"dense": "gemma3-1b", "moe": "grok-1-314b", "ssm": "mamba2-2.7b"}
+# (family, smoke architecture, init seed)
+ARCHS = {"dense": ("gemma3-1b", 11), "moe": ("grok-1-314b", 500),
+         "ssm": ("mamba2-2.7b", 999)}
 
 
 @pytest.fixture(scope="module")
 def models():
     out = {}
-    for fam, arch in ARCHS.items():
+    for fam, (arch, seed) in ARCHS.items():
         cfg = smoke_config(arch)
         m = Model(cfg, param_dtype=jnp.float32)
-        out[fam] = (m, m.init(jax.random.PRNGKey(hash(fam) % 1000)))
+        out[fam] = (m, m.init(jax.random.PRNGKey(seed)))
     return out
-
-
-def _decode_steps(build, m, params, cache, tok, *, stacked, steps=3):
-    """Run ``steps`` greedy decode steps through a (re-bound) template."""
-    tmpl = build(m, params, int(tok.shape[0]), stacked=stacked)
-    vj = VLIWJit(max_group=8)
-    logits = []
-    for _ in range(steps):
-        prog = tmpl.bind(stream_id=0, tokens=tok, cache=cache)
-        vj.run([prog])
-        logits.append(np.asarray(prog.env["logits"]))
-        cache = prog.env["cache"]
-        tok = jnp.argmax(prog.env["logits"],
-                         axis=-1).astype(jnp.int32)[:, None]
-    return logits, cache
 
 
 def _setup(m, params, B, S=12, CL=32):
@@ -68,91 +54,63 @@ def _setup(m, params, B, S=12, CL=32):
 
 
 # ---------------------------------------------------------------------------
-# bit-identity: stacked vs per-layer oracle
+# the stacked decode templates against the plain reference
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize(
     "fam,batch",
     [(f, b) for f in ("dense", "moe", "ssm") for b in (1, 2, 4)]
     + [("dense", 16), ("ssm", 16)])
-def test_stacked_decode_bit_identical_to_per_layer(fam, batch, models):
-    """The tentpole contract: the scanned layer body computes the SAME
-    BITS as the per-layer executor dispatch — logits AND every recurrent
-    cache leaf, over multiple steps (divergence would compound). At batch
-    16 the body runs one 16-row m-tile (``decode_tile``), the per-layer
-    path two of the executor's 8 rows, at the same bk."""
+def test_stacked_decode_matches_reference(fam, batch, models):
+    """Over 3 steps, one template's logits and every cache leaf match
+    ``Model.decode_step``. Both sides take the reference's greedy tokens,
+    so a near-tie cannot compound into different streams. The tolerance
+    is float32's, 2e-4 as in the other reference tests: the template's
+    GEMMs accumulate K in the Pallas kernel's bk blocks, the reference's
+    in XLA's dot order."""
     m, params = models[fam]
-    cache0, tok = _setup(m, params, batch)
-    want, want_cache = _decode_steps(DECODE_BUILDERS[fam], m, params,
-                                     cache0, tok, stacked=False)
-    got, got_cache = _decode_steps(DECODE_BUILDERS[fam], m, params,
-                                   cache0, tok, stacked=True)
-    for s, (a, b) in enumerate(zip(got, want)):
-        np.testing.assert_array_equal(a, b, err_msg=f"step {s}")
-    for leaf in want_cache["layers"]:
-        np.testing.assert_array_equal(
-            np.asarray(got_cache["layers"][leaf]),
-            np.asarray(want_cache["layers"][leaf]), err_msg=leaf)
-
-
-@pytest.mark.parametrize("prompt_len", [5, 12, 100])
-def test_stacked_prefill_bit_identical_to_per_layer(prompt_len, models):
-    """Buckets 8, 16 and 128: the stacked body runs ``prefill_tile``, the
-    per-layer path the executor's tile, at the same bk."""
-    m, params = models["dense"]
-    cfg = m.cfg
-    Sp = prefill_bucket(prompt_len)
-    toks = jax.random.randint(jax.random.PRNGKey(4), (1, prompt_len), 0,
-                              cfg.vocab_size)
-    padded = jnp.pad(toks, ((0, 0), (0, Sp - prompt_len)))
-    outs = {}
-    for stacked in (True, False):
-        cache = m.init_cache(2, max(32, Sp))
-        tmpl = build_dense_prefill_template(m, params, Sp, stacked=stacked)
-        prog = tmpl.bind(stream_id=0, tokens=padded, cache=cache,
-                         env_extra={"real_len": prompt_len, "slot": 1})
-        VLIWJit(max_group=8).run([prog])
-        outs[stacked] = prog.env
-    np.testing.assert_array_equal(np.asarray(outs[True]["logits"]),
-                                  np.asarray(outs[False]["logits"]))
-    for leaf in ("k", "v"):
-        np.testing.assert_array_equal(
-            np.asarray(outs[True]["cache"]["layers"][leaf]),
-            np.asarray(outs[False]["cache"]["layers"][leaf]))
+    ref_cache, tok = _setup(m, params, batch)
+    cache = ref_cache
+    tmpl = DECODE_BUILDERS[fam](m, params, batch)
+    vj = VLIWJit(max_group=8)
+    for step in range(3):
+        want, ref_cache = m.decode_step(params, tok, ref_cache)
+        prog = tmpl.bind(stream_id=0, tokens=tok, cache=cache)
+        vj.run([prog])
+        cache = prog.env["cache"]
+        np.testing.assert_allclose(prog.env["logits"], want[:, -1],
+                                   rtol=2e-4, atol=2e-4,
+                                   err_msg=f"step {step}")
+        for leaf in ref_cache["layers"]:
+            np.testing.assert_allclose(
+                np.asarray(cache["layers"][leaf]),
+                np.asarray(ref_cache["layers"][leaf]), rtol=2e-4,
+                atol=2e-4, err_msg=f"step {step} {leaf}")
+        np.testing.assert_array_equal(np.asarray(cache["pos"]),
+                                      np.asarray(ref_cache["pos"]))
+        tok = jnp.argmax(want[:, -1], axis=-1).astype(jnp.int32)[:, None]
 
 
 def test_stacked_template_one_body_stage_per_substack(models):
     """Structure: stage count is O(1) in depth — one StackedGemmStage per
-    homogeneous sub-stack, never a per-layer emission."""
+    homogeneous sub-stack, and the unembedding head is the only plain
+    GemmStage left."""
     m, params = models["dense"]
-    tmpl = build_dense_decode_template(m, params, 2, stacked=True)
+    tmpl = build_dense_decode_template(m, params, 2)
     bodies = [st for st in tmpl.stages if isinstance(st, StackedGemmStage)]
     assert len(bodies) == len(partition_layers(
         m.cfg.global_layer_flags()))
-    per_layer = build_dense_decode_template(m, params, 2, stacked=False)
-    assert len(tmpl.stages) < len(per_layer.stages)
+    assert [st.tag for st in tmpl.stages
+            if isinstance(st, GemmStage)] == ["unembed"]
 
 
 # ---------------------------------------------------------------------------
-# serving: engine-level token identity, hit rate, hot-swap
+# serving: hit rate, hot-swap
 # ---------------------------------------------------------------------------
 
 def _tokens(rep):
     return [r.tokens_out for r in sorted(rep.requests,
                                          key=lambda r: r.req_id)]
-
-
-def test_engine_stacked_vs_per_layer_token_identity(models):
-    m, params = models["dense"]
-    trace = [ServeRequest(0, "a", 0.0, 8, 4, 1.0),
-             ServeRequest(1, "a", 1e-4, 6, 4, 1.0)]
-    reps = {}
-    for stacked in (True, False):
-        eng = ServingEngine([Tenant("a", m, params, cache_len=32,
-                                    max_batch=2)], mode="vliw",
-                            stacked_layers=stacked)
-        reps[stacked] = eng.run(trace)
-    assert _tokens(reps[True]) == _tokens(reps[False])
 
 
 def test_stacked_steady_state_hit_rate_and_guard(models):
@@ -164,7 +122,6 @@ def test_stacked_steady_state_hit_rate_and_guard(models):
     trace = [ServeRequest(0, "a", 0.0, 8, steps + 1, 1.0)]
     eng = ServingEngine([Tenant("a", m, params, cache_len=32,
                                 max_batch=2)], mode="vliw")
-    assert eng.stacked_layers          # stacked is the default regime
     rep = eng.run(trace)
     pc = rep.jit.plan_cache
     assert pc.hit_rate >= (steps - 1) / steps - 1e-9
@@ -307,8 +264,8 @@ def test_depth_48_serves_end_to_end():
     assert all(len(t) == 3 for t in toks)
     # the stacked emission really is depth-independent: the 48-layer
     # template has exactly as many stages as a 2-layer one
-    t48 = build_dense_decode_template(m, params, 1, stacked=True)
+    t48 = build_dense_decode_template(m, params, 1)
     shallow = Model(smoke_config("granite-34b"), param_dtype=jnp.float32)
     p2 = shallow.init(jax.random.PRNGKey(8))
-    t2 = build_dense_decode_template(shallow, p2, 1, stacked=True)
+    t2 = build_dense_decode_template(shallow, p2, 1)
     assert len(t48.stages) == len(t2.stages)
